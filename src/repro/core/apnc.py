@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.kernels_fn import Kernel
+from repro.policy import MATMUL_PRECISION
 
 Array = jax.Array
 Discrepancy = Literal["l2", "l1"]
@@ -65,7 +66,7 @@ def embed_block(X: Array, landmarks_b: Array, R_b: Array, kernel: Kernel) -> Arr
     same contraction fused (see repro/kernels). Here: the pure-jnp fallback.
     """
     K = kernel.gram(X, landmarks_b)  # (n, l_b)
-    return K @ R_b.T  # (n, m_b)
+    return jnp.dot(K, R_b.T, precision=MATMUL_PRECISION)  # (n, m_b)
 
 
 def embed(X: Array, coeffs: APNCCoefficients) -> Array:
@@ -91,7 +92,8 @@ def pairwise_discrepancy(Y: Array, C: Array, discrepancy: Discrepancy) -> Array:
     if discrepancy == "l2":
         yy = jnp.sum(Y * Y, axis=-1, keepdims=True)  # (n, 1)
         cc = jnp.sum(C * C, axis=-1)[None, :]  # (1, k)
-        d2 = jnp.maximum(yy - 2.0 * (Y @ C.T) + cc, 0.0)
+        cross = jnp.dot(Y, C.T, precision=MATMUL_PRECISION)  # (n, k)
+        d2 = jnp.maximum(yy - 2.0 * cross + cc, 0.0)
         return jnp.sqrt(d2)
     if discrepancy == "l1":
         def one(c):
@@ -113,6 +115,6 @@ def sufficient_stats(Y: Array, labels: Array, k: int) -> tuple[Array, Array]:
     clustering phase. Z: (k, m), g: (k,).
     """
     onehot = jax.nn.one_hot(labels, k, dtype=Y.dtype)  # (n, k)
-    Z = onehot.T @ Y  # (k, m)
+    Z = jnp.dot(onehot.T, Y, precision=MATMUL_PRECISION)  # (k, m)
     g = jnp.sum(onehot, axis=0)  # (k,)
     return Z, g

@@ -21,8 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
-
 from repro.core.apnc import Discrepancy, pairwise_discrepancy
 from repro.core.lloyd import assign_stats, centroid_update
 from repro.policy import ComputePolicy, resolve_policy
@@ -58,7 +56,7 @@ def distributed_embed(
 
         return embed.transform(p, x_shard, pol)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         block,
         mesh=mesh,
         # P() is a spec PREFIX for the params pytree: every leaf replicated.
@@ -132,7 +130,7 @@ def _distributed_lloyd(
         D = pairwise_discrepancy(y_shard, c, discrepancy)
         return jnp.argmin(D, axis=-1).astype(jnp.int32), c
 
-    fn = shard_map(
+    fn = jax.shard_map(
         shard_fn,
         mesh=mesh,
         in_specs=(P(axes), P()),
@@ -182,7 +180,7 @@ def _distributed_lloyd_costs(
         D = pairwise_discrepancy(y_shard, c, discrepancy)
         return jnp.argmin(D, axis=-1).astype(jnp.int32), c, costs
 
-    fn = shard_map(
+    fn = jax.shard_map(
         shard_fn,
         mesh=mesh,
         in_specs=(P(axes), P()),

@@ -17,6 +17,8 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
+from repro.policy import MATMUL_PRECISION
+
 Array = jax.Array
 
 
@@ -28,7 +30,7 @@ def _sq_dists(X: Array, Z: Array) -> Array:
     """
     xx = jnp.sum(X * X, axis=-1, keepdims=True)  # (n, 1)
     zz = jnp.sum(Z * Z, axis=-1, keepdims=True).T  # (1, l)
-    cross = X @ Z.T  # (n, l)
+    cross = jnp.dot(X, Z.T, precision=MATMUL_PRECISION)  # (n, l)
     return jnp.maximum(xx - 2.0 * cross + zz, 0.0)
 
 
@@ -46,12 +48,13 @@ class Kernel:
         """Dense kernel matrix K[i, j] = kappa(X[i], Z[j]); shape (n, l)."""
         if self.name == "rbf":
             return jnp.exp(-self.gamma * _sq_dists(X, Z))
+        dot = jnp.dot(X, Z.T, precision=MATMUL_PRECISION)
         if self.name == "poly":
-            return (X @ Z.T + self.coef0) ** self.degree
+            return (dot + self.coef0) ** self.degree
         if self.name == "tanh":
-            return jnp.tanh(self.scale * (X @ Z.T) + self.coef0)
+            return jnp.tanh(self.scale * dot + self.coef0)
         if self.name == "linear":
-            return X @ Z.T
+            return dot
         raise ValueError(f"unknown kernel {self.name!r}")
 
     def diag(self, X: Array) -> Array:

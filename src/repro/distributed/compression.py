@@ -19,9 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro import compat
-from repro.compat import shard_map
-
 Array = jax.Array
 
 
@@ -40,7 +37,7 @@ def compressed_psum(grads: Any, error: Any, axes: tuple[str, ...]) -> tuple[Any,
     """MUST run inside shard_map over `axes`. Returns (mean_grads, new_error)."""
     n = 1
     for a in axes:
-        n *= compat.axis_size(a)
+        n *= jax.lax.axis_size(a)
 
     def one(g, e):
         g32 = g.astype(jnp.float32) + e
@@ -84,7 +81,7 @@ def make_ddp_compressed_step(mesh: Mesh, loss_fn, opt_update, axes=("data",)):
     batch_spec = P(axes)
 
     def step(params, opt_state, err, batch):
-        return shard_map(
+        return jax.shard_map(
             shard_body,
             mesh=mesh,
             in_specs=(P(), P(), P(), batch_spec),

@@ -18,8 +18,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
-
 Array = jax.Array
 
 
@@ -78,7 +76,7 @@ def pipelined_apply(
         outs = jnp.where(sid == n_stages - 1, outs, jnp.zeros_like(outs))
         return jax.lax.psum(outs, axis)[None]
 
-    fn = shard_map(
+    fn = jax.shard_map(
         per_stage,
         mesh=mesh,
         in_specs=(P(axis), P(None)),

@@ -21,6 +21,7 @@ import jax.numpy as jnp
 from repro.core.apnc import APNCCoefficients, embed
 from repro.core.kernels_fn import Kernel
 from repro.embed.base import Embedding, EmbeddingProps, register_embedding
+from repro.policy import MATMUL_PRECISION
 
 Array = jax.Array
 
@@ -92,7 +93,8 @@ def _sd_block(key: Array, landmarks: Array, kernel: Kernel, m: int, t: int) -> A
     l = landmarks.shape[0]
     K_LL = kernel.gram(landmarks, landmarks)
     H = jnp.eye(l) - jnp.full((l, l), 1.0 / l)
-    G = H @ K_LL @ H  # centered gram
+    HK = jnp.dot(H, K_LL, precision=MATMUL_PRECISION)
+    G = jnp.dot(HK, H, precision=MATMUL_PRECISION)  # centered gram
     G = 0.5 * (G + G.T)  # fight asymmetry from roundoff before eigh
     lam, V = jnp.linalg.eigh(G)
     E = _inv_sqrt_clamped(lam)[:, None] * V.T  # (l, l) inverse square root factor
@@ -104,7 +106,8 @@ def _sd_block(key: Array, landmarks: Array, kernel: Kernel, m: int, t: int) -> A
         return jnp.zeros((l,)).at[sel].set(1.0)
 
     S = jax.vmap(one_row)(jax.random.split(key, m))  # (m, l)
-    R = (S @ E) @ H  # rows R_r = (sum_{v in T_r} E_v) H   [Alg 4 line 15]
+    # rows R_r = (sum_{v in T_r} E_v) H   [Alg 4 line 15]
+    R = jnp.dot(jnp.dot(S, E, precision=MATMUL_PRECISION), H, precision=MATMUL_PRECISION)
     # 1/sqrt(t) from Eq. (14) keeps projections O(1)-scaled; it is absorbed into
     # the constant beta of Property 4.4 but applying it keeps numerics tame.
     return R / jnp.sqrt(jnp.asarray(t, R.dtype))

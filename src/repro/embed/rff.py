@@ -24,6 +24,7 @@ import jax.numpy as jnp
 
 from repro.core.kernels_fn import Kernel
 from repro.embed.base import Embedding, EmbeddingProps, register_embedding
+from repro.policy import MATMUL_PRECISION
 
 Array = jax.Array
 
@@ -56,7 +57,7 @@ class RFFParams:
 
 def rff_transform(params: RFFParams, X: Array) -> Array:
     """Reference map: (n, d) -> (n, 2 m_half) f32 in [cos, sin] layout."""
-    proj = X @ params.W.astype(X.dtype)
+    proj = jnp.dot(X, params.W.astype(X.dtype), precision=MATMUL_PRECISION)
     scale = jnp.asarray(params.scale, proj.dtype)
     return scale * jnp.concatenate([jnp.cos(proj), jnp.sin(proj)], axis=-1)
 

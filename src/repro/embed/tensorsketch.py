@@ -25,6 +25,7 @@ import jax.numpy as jnp
 
 from repro.core.kernels_fn import Kernel
 from repro.embed.base import Embedding, EmbeddingProps, register_embedding
+from repro.policy import MATMUL_PRECISION
 
 Array = jax.Array
 
@@ -59,7 +60,9 @@ def tensorsketch_transform(params: TensorSketchParams, X: Array) -> Array:
             (X.shape[0], 1), jnp.sqrt(params.kernel.coef0), dtype=X.dtype
         )
         X = jnp.concatenate([X, const], axis=-1)
-    C = jnp.einsum("nd,pdm->pnm", X, params.S.astype(X.dtype))  # p count-sketches
+    C = jnp.einsum(  # p count-sketches
+        "nd,pdm->pnm", X, params.S.astype(X.dtype), precision=MATMUL_PRECISION
+    )
     F = jnp.prod(jnp.fft.fft(C.astype(jnp.float32), axis=-1), axis=0)
     return jnp.fft.ifft(F).real.astype(jnp.float32)
 

@@ -8,9 +8,10 @@ the un-fused XLA path reads it for the distance and again for the one-hot matmul
 
     grid = (n/bn,)
     centroids (k, m) live whole in VMEM (k*m <= ~256K elements at paper scales)
-    l2: D = yy - 2 Y C^T + cc          (MXU)
-    l1: D[:, c] = sum |Y - C[c]|       (VPU, fori over k)
-    labels = argmin D                   -> (bn, 1) i32 tile
+    l2: D = yy - 2 Y C^T + cc          (MXU); labels = argmin D
+    l1: fori over centroid rows C[c] (read from the ref): running min and
+        argmin of sum |Y - C[c]|       (VPU; no (bn, k) matrix is built)
+    labels                              -> (bn, 1) i32 tile
     Z (+)= onehot^T @ Y                 (MXU, revisited output block)
     g (+)= colsum onehot
 
@@ -24,32 +25,47 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
+from repro.policy import MATMUL_PRECISION
 
 Array = jax.Array
 
 DEFAULT_BN = 256
 
 
-def _distances(y, c, discrepancy: str):
-    """(bn, m) x (k, m) -> (bn, k) under the declared discrepancy, f32."""
+def _nearest(y, c_ref, discrepancy: str):
+    """Nearest centroid of each row of y (bn, m) among the rows of c_ref
+    (k, m): (labels (bn, 1) i32, min discrepancy (bn, 1) f32). l2's minimum
+    is SQUARED (same argmin); ties go to the lowest centroid index, as
+    jnp.argmin does."""
     if discrepancy == "l2":
+        c = c_ref[...].astype(jnp.float32)
         yy = jnp.sum(y * y, axis=1, keepdims=True)
         cc = jnp.sum(c * c, axis=1, keepdims=True).T
         cross = jax.lax.dot_general(
-            y, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            y, c, (((1,), (1,)), ((), ())), precision=MATMUL_PRECISION,
+            preferred_element_type=jnp.float32,
         )
-        return jnp.maximum(yy - 2.0 * cross + cc, 0.0)  # squared l2: same argmin
+        D = jnp.maximum(yy - 2.0 * cross + cc, 0.0)  # (bn, k)
+        labels = jnp.argmin(D, axis=1).astype(jnp.int32)
+        return labels[:, None], jnp.min(D, axis=1)[:, None]
     if discrepancy == "l1":
-        k = c.shape[0]
+        # One centroid row per step, read from the ref with a sublane slice:
+        # Mosaic has no dynamic lane slice, so D is never built column-wise.
+        def dist(ci):
+            row = c_ref[pl.ds(ci, 1), :].astype(jnp.float32)  # (1, m)
+            return jnp.sum(jnp.abs(y - row), axis=1, keepdims=True)  # (bn, 1)
 
-        def body(ci, D):
-            col = jnp.sum(jnp.abs(y - c[ci][None, :]), axis=1)  # (bn,)
-            return jax.lax.dynamic_update_index_in_dim(D, col, ci, axis=1)
+        def body(ci, carry):
+            best, arg = carry
+            d = dist(ci)
+            closer = d < best  # strict: the first minimum wins ties
+            return jnp.where(closer, d, best), jnp.where(closer, ci, arg)
 
-        D0 = jnp.zeros((y.shape[0], k), jnp.float32)
-        return jax.lax.fori_loop(0, k, body, D0)
+        init = (dist(0), jnp.zeros((y.shape[0], 1), jnp.int32))
+        best, arg = jax.lax.fori_loop(1, c_ref.shape[0], body, init)
+        return arg, best
     raise ValueError(f"unknown discrepancy {discrepancy!r}")
 
 
@@ -58,20 +74,18 @@ def _assign_kernel(
 ):
     i = pl.program_id(0)
     y = y_ref[...].astype(jnp.float32)  # (bn, m)
-    c = c_ref[...].astype(jnp.float32)  # (k, m)
-    k = c.shape[0]
-
-    D = _distances(y, c, discrepancy)  # (bn, k)
-    labels = jnp.argmin(D, axis=1).astype(jnp.int32)  # (bn,)
+    k = c_ref.shape[0]
+    labels, _ = _nearest(y, c_ref, discrepancy)  # (bn, 1)
 
     row = i * bn + jax.lax.broadcasted_iota(jnp.int32, (bn, 1), 0)  # global row ids
     valid = (row < n_actual).astype(jnp.float32)  # (bn, 1)
 
-    onehot = (labels[:, None] == jax.lax.broadcasted_iota(jnp.int32, (bn, k), 1))
+    onehot = (labels == jax.lax.broadcasted_iota(jnp.int32, (bn, k), 1))
     onehot = onehot.astype(jnp.float32) * valid  # masked (bn, k)
 
     z_contrib = jax.lax.dot_general(
-        onehot, y, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        onehot, y, (((0,), (0,)), ((), ())), precision=MATMUL_PRECISION,
+        preferred_element_type=jnp.float32,
     )  # (k, m)
     g_contrib = jnp.sum(onehot, axis=0, keepdims=True).T  # (k, 1)
 
@@ -85,7 +99,7 @@ def _assign_kernel(
         z_ref[...] += z_contrib
         g_ref[...] += g_contrib
 
-    lab_ref[...] = labels[:, None]
+    lab_ref[...] = labels
 
 
 def apnc_assign_padded(
@@ -123,7 +137,7 @@ def apnc_assign_padded(
             jax.ShapeDtypeStruct((k, 1), jnp.float32),
             jax.ShapeDtypeStruct((n, 1), jnp.int32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,

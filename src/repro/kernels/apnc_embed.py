@@ -24,9 +24,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
-
 from repro.core.kernels_fn import Kernel
+from repro.policy import MATMUL_PRECISION
 
 Array = jax.Array
 
@@ -62,7 +61,8 @@ def _embed_kernel(x_ref, l_ref, r_ref, y_ref, s_acc, xx_acc, ll_acc, *, kernel: 
     x = x_ref[...].astype(jnp.float32)  # (bn, bd)
     l = l_ref[...].astype(jnp.float32)  # (bl, bd)
     s_acc[...] += jax.lax.dot_general(
-        x, l, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        x, l, (((1,), (1,)), ((), ())), precision=MATMUL_PRECISION,
+        preferred_element_type=jnp.float32,
     )
     if kernel.name == "rbf":  # norms ride along in the same d-pass
         xx_acc[...] += jnp.sum(x * x, axis=1, keepdims=True)  # (bn, 1)
@@ -73,7 +73,8 @@ def _embed_kernel(x_ref, l_ref, r_ref, y_ref, s_acc, xx_acc, ll_acc, *, kernel: 
         K = _apply_kernel_nonlin(kernel, s_acc[...], xx_acc[...], ll_acc[...])
         r = r_ref[...].astype(jnp.float32)  # (m, bl)
         contrib = jax.lax.dot_general(
-            K, r, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            K, r, (((1,), (1,)), ((), ())), precision=MATMUL_PRECISION,
+            preferred_element_type=jnp.float32,
         )  # (bn, m)
 
         @pl.when(j == 0)
@@ -122,7 +123,7 @@ def apnc_embed_block(
             pltpu.VMEM((bn, 1), jnp.float32),
             pltpu.VMEM((1, bl), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,

@@ -13,8 +13,8 @@ this kernel eliminates that traffic entirely and halves the dispatch count.
     [rff]        S = X W   ; Y = s [cos(S), sin(S)]             (MXU+VPU)
     [dequant]    Y = Yq * scale          # quantized staged cache (Y-mode)
     shared epilogue (same math as apnc_assign + core.lloyd.block_cost):
-        D = e(Y, C)                      # l2 squared (same argmin) or l1
-        labels = argmin D                -> (bn, 1) i32 tile
+        labels, min e = nearest(Y, C)    # l2 squared (same argmin) or l1
+                                         -> (bn, 1) i32 / f32 tiles
         Z (+)= onehot^T @ Y              (MXU, revisited output block)
         g (+)= colsum onehot
         cost (+)= sum_valid min e        # sqrt'd for l2: block_cost's units
@@ -34,11 +34,12 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
 from repro.core.kernels_fn import Kernel
-from repro.kernels.apnc_assign import _distances
+from repro.kernels.apnc_assign import _nearest
 from repro.kernels.apnc_embed import _apply_kernel_nonlin
+from repro.policy import MATMUL_PRECISION
 
 Array = jax.Array
 
@@ -46,28 +47,28 @@ DEFAULT_BN = 256
 
 
 def _assign_reduce(
-    i, y, c, z_ref, g_ref, lab_ref, cost_ref, *, discrepancy: str, n_actual: int, bn: int
+    i, y, c_ref, z_ref, g_ref, lab_ref, cost_ref,
+    *, discrepancy: str, n_actual: int, bn: int,
 ):
-    """Shared fused epilogue: distances, labels, masked (Z, g) and cost tiles."""
-    k = c.shape[0]
-    D = _distances(y, c, discrepancy)  # (bn, k); l2 is SQUARED (same argmin)
-    labels = jnp.argmin(D, axis=1).astype(jnp.int32)  # (bn,)
+    """Shared fused epilogue: nearest centroid, masked (Z, g) and cost tiles."""
+    k = c_ref.shape[0]
+    labels, mind = _nearest(y, c_ref, discrepancy)  # (bn, 1); l2 min SQUARED
 
     row = i * bn + jax.lax.broadcasted_iota(jnp.int32, (bn, 1), 0)  # global row ids
     valid = (row < n_actual).astype(jnp.float32)  # (bn, 1)
 
-    onehot = (labels[:, None] == jax.lax.broadcasted_iota(jnp.int32, (bn, k), 1))
+    onehot = (labels == jax.lax.broadcasted_iota(jnp.int32, (bn, k), 1))
     onehot = onehot.astype(jnp.float32) * valid  # masked (bn, k)
 
     z_contrib = jax.lax.dot_general(
-        onehot, y, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        onehot, y, (((0,), (0,)), ((), ())), precision=MATMUL_PRECISION,
+        preferred_element_type=jnp.float32,
     )  # (k, m)
     g_contrib = jnp.sum(onehot, axis=0, keepdims=True).T  # (k, 1)
 
-    mind = jnp.min(D, axis=1)  # (bn,)
     if discrepancy == "l2":  # block_cost reports sqrt'd l2 — match its units
         mind = jnp.sqrt(jnp.maximum(mind, 0.0))
-    cost_contrib = jnp.sum(mind[:, None] * valid).reshape(1, 1)
+    cost_contrib = jnp.sum(mind * valid).reshape(1, 1)
 
     @pl.when(i == 0)
     def _init():
@@ -81,7 +82,7 @@ def _assign_reduce(
         g_ref[...] += g_contrib
         cost_ref[...] += cost_contrib
 
-    lab_ref[...] = labels[:, None]
+    lab_ref[...] = labels
 
 
 def _apnc_step_kernel(
@@ -92,7 +93,8 @@ def _apnc_step_kernel(
     x = x_ref[...].astype(jnp.float32)  # (bn, d)
     lm = l_ref[...].astype(jnp.float32)  # (l, d)
     S = jax.lax.dot_general(
-        x, lm, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        x, lm, (((1,), (1,)), ((), ())), precision=MATMUL_PRECISION,
+        preferred_element_type=jnp.float32,
     )  # (bn, l)
     if kernel.name == "rbf":
         xx = jnp.sum(x * x, axis=1, keepdims=True)  # (bn, 1)
@@ -102,11 +104,11 @@ def _apnc_step_kernel(
     K = _apply_kernel_nonlin(kernel, S, xx, ll)
     r = r_ref[...].astype(jnp.float32)  # (m, l)
     y = jax.lax.dot_general(
-        K, r, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        K, r, (((1,), (1,)), ((), ())), precision=MATMUL_PRECISION,
+        preferred_element_type=jnp.float32,
     )  # (bn, m): padded R rows are zero -> padded Y columns are exactly 0
-    c = c_ref[...].astype(jnp.float32)  # (k, m)
     _assign_reduce(
-        i, y, c, z_ref, g_ref, lab_ref, cost_ref,
+        i, y, c_ref, z_ref, g_ref, lab_ref, cost_ref,
         discrepancy=discrepancy, n_actual=n_actual, bn=bn,
     )
 
@@ -161,7 +163,7 @@ def fused_apnc_step(
             jax.ShapeDtypeStruct((n, 1), jnp.int32),
             jax.ShapeDtypeStruct((1, 1), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
@@ -179,9 +181,8 @@ def _dequant_step_kernel(
     # broadcasts over the row axis. Zero payload rows/cols dequantize
     # to exactly 0, so the caller's zero padding matches zero-padded C.
     y = yq_ref[...].astype(jnp.float32) * s_ref[...]
-    c = c_ref[...].astype(jnp.float32)
     _assign_reduce(
-        i, y, c, z_ref, g_ref, lab_ref, cost_ref,
+        i, y, c_ref, z_ref, g_ref, lab_ref, cost_ref,
         discrepancy=discrepancy, n_actual=n_actual, bn=bn,
     )
 
@@ -234,7 +235,7 @@ def fused_dequant_step(
             jax.ShapeDtypeStruct((n, 1), jnp.int32),
             jax.ShapeDtypeStruct((1, 1), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
@@ -249,7 +250,8 @@ def _rff_step_kernel(
     x = x_ref[...].astype(jnp.float32)  # (bn, d)
     w = w_ref[...].astype(jnp.float32)  # (d, mh_pad)
     S = jax.lax.dot_general(
-        x, w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        x, w, (((1,), (0,)), ((), ())), precision=MATMUL_PRECISION,
+        preferred_element_type=jnp.float32,
     )  # (bn, mh_pad)
     # Padded W columns project to 0, but cos(0) = 1: re-zero those lanes so the
     # padded Y columns stay exactly 0 (matching the zero-padded centroids).
@@ -258,9 +260,8 @@ def _rff_step_kernel(
     y = jnp.concatenate(
         [scale * jnp.cos(S) * keep, scale * jnp.sin(S) * keep], axis=1
     )  # (bn, 2*mh_pad): the wrapper lays C out in the same padded [cos|sin]
-    c = c_ref[...].astype(jnp.float32)  # (k, 2*mh_pad)
     _assign_reduce(
-        i, y, c, z_ref, g_ref, lab_ref, cost_ref,
+        i, y, c_ref, z_ref, g_ref, lab_ref, cost_ref,
         discrepancy=discrepancy, n_actual=n_actual, bn=bn,
     )
 
@@ -315,7 +316,7 @@ def fused_rff_step(
             jax.ShapeDtypeStruct((n, 1), jnp.int32),
             jax.ShapeDtypeStruct((1, 1), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,

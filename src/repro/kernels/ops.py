@@ -1,8 +1,8 @@
 """jit'd public wrappers for the Pallas kernels: padding, dispatch, unpadding.
 
-On non-TPU backends the kernels run with interpret=True (the kernel body executes
-in Python/XLA on CPU) — this is how this container validates them; on TPU the same
-BlockSpecs compile to Mosaic. `interpret=None` auto-detects.
+On a TPU the kernels compile to Mosaic. On any other backend they run with
+interpret=True (the kernel body executes as XLA on the CPU), which is how the
+tests run them. `interpret=None` picks by backend.
 """
 from __future__ import annotations
 
@@ -14,6 +14,7 @@ import jax.numpy as jnp
 
 from repro.core.apnc import APNCCoefficients
 from repro.core.kernels_fn import Kernel
+from repro.embed.rff import RFFParams
 from repro.kernels import apnc_assign as _assign
 from repro.kernels import apnc_embed as _embed
 from repro.kernels import lloyd_step as _lloyd_step
@@ -298,10 +299,6 @@ def fused_member(params) -> str | None:
         return None
     if isinstance(params, APNCCoefficients):
         return "apnc" if params.q == 1 else None
-    try:
-        from repro.embed.rff import RFFParams
-    except ImportError:  # registry member not importable: no fused path
-        return None
     if isinstance(params, RFFParams):
         return "rff"
     return None

@@ -28,7 +28,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
+from repro.policy import MATMUL_PRECISION
 
 Array = jax.Array
 
@@ -47,7 +47,8 @@ def _rff_kernel(x_ref, w_ref, yc_ref, ys_ref, s_acc, *, scale: float, nd: int):
     x = x_ref[...].astype(jnp.float32)  # (bn, bd)
     w = w_ref[...].astype(jnp.float32)  # (bd, bm)
     s_acc[...] += jax.lax.dot_general(
-        x, w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        x, w, (((1,), (0,)), ((), ())), precision=MATMUL_PRECISION,
+        preferred_element_type=jnp.float32,
     )
 
     @pl.when(kd == nd - 1)
@@ -94,7 +95,7 @@ def rff_embed_block(
             jax.ShapeDtypeStruct((n, m), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((bn, bm), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -3,8 +3,7 @@ this module never touches jax device state (dry-run sets XLA_FLAGS first)."""
 from __future__ import annotations
 
 import jax
-
-from repro.compat import make_mesh as _compat_make_mesh
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -12,11 +11,12 @@ def make_production_mesh(*, multi_pod: bool = False):
     leading "pod" axis (DCN-ish links; gradients + nothing else cross it)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _compat_make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    return _compat_make_mesh(shape, axes)
+    """jax.make_mesh with Auto axis types."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(data: int = 4, model: int = 2):

@@ -22,6 +22,14 @@ import jax
 Precision = Literal["f32", "bf16"]
 CacheDtype = Literal["f32", "bf16", "int8"]
 
+# f32 means f32 on every backend. At DEFAULT precision a TPU runs an f32
+# matmul as one bf16 pass (about three significant digits): on a v5e that
+# moved the rbf gram's cross term, the RFF phases and the l2 distances enough
+# to change labels and inertia against an f32 reference. Every matmul on the
+# clustering path, jnp and Pallas alike (Mosaic takes DEFAULT or HIGHEST
+# only), asks for this precision.
+MATMUL_PRECISION = jax.lax.Precision.HIGHEST
+
 
 @dataclasses.dataclass(frozen=True)
 class ComputePolicy:

@@ -4,6 +4,7 @@ the main pytest process). Prints one JSON dict of results."""
 import os
 
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+os.environ["JAX_PLATFORMS"] = "cpu"  # CPU-only: never take a chip
 
 import json
 import sys

@@ -22,7 +22,7 @@ flags = " ".join(
     if not f.startswith("--xla_force_host_platform_device_count")
 )
 os.environ["XLA_FLAGS"] = f"{flags} --xla_force_host_platform_device_count=8"
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+os.environ["JAX_PLATFORMS"] = "cpu"  # CPU-only: never take a chip
 sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 
 import jax  # noqa: E402  (after the device forcing)

@@ -81,7 +81,8 @@ def test_plan_matches_unfused_chain(block, name, pol):
 @pytest.mark.parametrize("name", available_embeddings())
 def test_plan_y_mode_matches_assign_chain(block, name):
     """Y-mode plan (embedded blocks: local backend, sweep cache) reproduces
-    assign_stats + block_cost exactly."""
+    assign_stats + block_cost: identical labels, and (Z, g, cost) equal up
+    to f32 summation order."""
     params = _fit_member(name, block)
     pol = ComputePolicy(pallas=False)
     Y = ops.embed_block_map(block, params, policy=pol)
@@ -90,9 +91,20 @@ def test_plan_y_mode_matches_assign_chain(block, name):
     Z, g, labels, cost = plan.step(Y, C)
     Zr, gr, lr = assign_stats(Y, C, K, params.discrepancy, policy=pol)
     np.testing.assert_array_equal(np.asarray(labels), np.asarray(lr))
-    np.testing.assert_array_equal(np.asarray(Z), np.asarray(Zr))
-    np.testing.assert_array_equal(np.asarray(g), np.asarray(gr))
-    assert float(cost) == float(block_cost(Y, C, params.discrepancy))
+
+    # The plan jits the one-hot matmul and the cost reduction into one
+    # program, so XLA may sum the same f32 terms in another order. Each entry
+    # is a sum of at most n=300 terms: the reordering error is a few ulps of
+    # the largest entry (measured: at most 4e-7 of it), far below 1e-5 of it.
+    def close(a, b):
+        b = np.asarray(b)
+        np.testing.assert_allclose(
+            np.asarray(a), b, rtol=1e-5, atol=1e-5 * float(np.abs(b).max())
+        )
+
+    close(Z, Zr)
+    close(g, gr)
+    close(cost, block_cost(Y, C, params.discrepancy))
 
 
 def test_fused_members_fuse_and_tensorsketch_falls_back(block):
@@ -223,6 +235,7 @@ assert rel <= 0.02, rel
 print("OK", agree, rel)
 """
     env = {"XLA_FLAGS": "--xla_force_host_platform_device_count=8",
+           "JAX_PLATFORMS": "cpu",
            "PYTHONPATH": str(Path(__file__).resolve().parents[1] / "src")}
     import os
     out = subprocess.run([sys.executable, "-c", code],
