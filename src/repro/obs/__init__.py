@@ -3,11 +3,14 @@
 Three layers, one import:
 
   * tracer  — thread-safe `span()` context managers on named lanes (driver +
-    one lane per device producer), near-free and allocation-free when
-    disabled; export to Chrome trace-event JSON (Perfetto) or JSONL.
+    one lane per device producer), each with an id and its parent's, each
+    mirrored as a `jax.profiler.TraceAnnotation` so a profile shows them
+    beside the device ops; `Tracer.record()` for spans known after the fact; a gc
+    hook and a stall watchdog while enabled. Near-free and allocation-free
+    when disabled; export to Chrome trace-event JSON (Perfetto) or JSONL.
   * metrics — always-on counters/gauges/histograms in one registry
     (`engine.blocks_read`, `engine.bytes_h2d`, `engine.passes.<label>`,
-    `serve.latency_ms`, ...), scoped by snapshot/delta, thread-safe under the
+    `serve.batch_size`, ...), scoped by snapshot/delta, thread-safe under the
     sharded executor's D producers.
   * report  — `FitReport`, the structured record every backend fit and sweep
     returns (phase wall-times, per-iteration inertia trajectory, pass counts,
@@ -50,7 +53,6 @@ from repro.obs.tracer import (
     clear_trace,
     disable_tracing,
     enable_tracing,
-    instant,
     set_lane,
     span,
     tracing_enabled,
@@ -61,7 +63,7 @@ __all__ = [
     "Counter", "FitReport", "Gauge", "Histogram", "MetricsRegistry", "Span",
     "Tracer",
     "chrome_trace_events", "clear_trace", "counter", "delta",
-    "disable_tracing", "enable_tracing", "gauge", "histogram", "instant",
+    "disable_tracing", "enable_tracing", "gauge", "histogram",
     "join_fit_roofline", "report_from_metrics_delta", "reset_metrics",
     "roofline_join", "scoped", "set_lane", "snapshot", "span",
     "tracing_enabled", "write_chrome_trace", "write_jsonl", "write_trace",

@@ -6,7 +6,7 @@ of events under a `traceEvents` key. We emit:
   * one `ph: "M"` (metadata) `thread_name` event per lane, naming the row —
     "main" for the driver, "producer:<device>" for each prefetcher thread;
   * one `ph: "X"` (complete) event per span, `ts`/`dur` in MICROseconds,
-    span attributes under `args`.
+    span attributes under `args`, with the span's `id` and its `parent`'s.
 
 `pid` is constant (one process); `tid` is the lane index in first-seen order,
 so a sharded fit renders with one swimlane per device producer above the
@@ -48,7 +48,8 @@ def chrome_trace_events(spans: Sequence[Span], *, epoch: float = 0.0) -> list:
             "tid": tids[s.lane],
             "ts": (epoch + s.t0) * 1e6,
             "dur": s.dur * 1e6,
-            "args": {k: _jsonable(v) for k, v in s.attrs.items()},
+            "args": dict({k: _jsonable(v) for k, v in s.attrs.items()},
+                         id=s.id, parent=s.parent),
         })
     return events
 
@@ -73,7 +74,8 @@ def write_chrome_trace(path: str | Path, *, tracer: Tracer | None = None) -> Pat
 
 
 def write_jsonl(path: str | Path, *, tracer: Tracer | None = None) -> Path:
-    """One JSON object per span: {name, cat, lane, t0, dur, ...attrs}."""
+    """One JSON object per span: {name, cat, lane, t0, dur, id, parent,
+    ...attrs}."""
     tracer = tracer if tracer is not None else TRACER
     path = Path(path)
     path.parent.mkdir(parents=True, exist_ok=True)
@@ -81,7 +83,7 @@ def write_jsonl(path: str | Path, *, tracer: Tracer | None = None) -> Path:
         for s in tracer.spans():
             rec = {
                 "name": s.name, "cat": s.cat, "lane": s.lane,
-                "t0": s.t0, "dur": s.dur,
+                "t0": s.t0, "dur": s.dur, "id": s.id, "parent": s.parent,
             }
             rec.update({k: _jsonable(v) for k, v in s.attrs.items()})
             f.write(json.dumps(rec) + "\n")

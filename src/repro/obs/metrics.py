@@ -2,16 +2,16 @@
 
 Spans answer "where did the time go" when someone turns tracing on; metrics
 answer "how much work happened" all the time — blocks read, bytes streamed
-host-to-device, engine passes per label (the `PASS_COUNTS` successor), serve
-latencies. Everything is registered in one process-wide `MetricsRegistry`
-keyed by dotted names (`engine.blocks_read`, `serve.latency_ms`, ...), and
+host-to-device, engine passes per label, serve batch sizes and waits.
+Everything is registered in one process-wide `MetricsRegistry`
+keyed by dotted names (`engine.blocks_read`, `serve.batch_size`, ...), and
 every mutation is lock-protected so the sharded executor's D producer threads
 can bump the same counter without losing increments.
 
 Measurement scoping is by snapshot, not by destructive reset: take
 `snapshot()` before, `snapshot()` after, `delta()` the two — concurrent users
 (nested fits, background serving) are unaffected. `reset(prefix)` exists for
-tests that want an absolute zero (the `reset_pass_counts()` shim).
+tests that want an absolute zero (`stream.engine.reset_pass_counts()`).
 """
 from __future__ import annotations
 

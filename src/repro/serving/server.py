@@ -123,6 +123,7 @@ class ServingTier:
         self._running = False
         self._thread: threading.Thread | None = None
         self._e2e = obs.histogram("serve.e2e_latency_ms")
+        self._intake_wait = obs.counter("serve.intake_wait_s")
 
     # ------------------------------------------------------------ lifecycle
 
@@ -196,6 +197,7 @@ class ServingTier:
                 max_delay_s=self.max_delay_s,
                 clock=self.clock,
                 on_result=self._deliver,
+                name=name,
             )
             self._batchers[name] = b
         return b
@@ -266,6 +268,9 @@ class ServingTier:
                 drained = list(self._intake)
                 self._intake.clear()
                 running = self._running
+            if drained:  # time the drained requests waited in intake
+                self._intake_wait.inc(len(drained) * self.clock()
+                                      - sum(req.t_submit for req, _ in drained))
             for req, fut in drained:
                 # may flush inline when a batch fills — that is the fast path
                 self._batcher(req.model).submit((req, fut), req.x)

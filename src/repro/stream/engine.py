@@ -22,7 +22,6 @@ devices concurrently — D mappers pulling their own HDFS blocks.
 """
 from __future__ import annotations
 
-import collections
 import queue
 import threading
 import time
@@ -57,27 +56,17 @@ def block_nbytes(blk) -> int:
         return blk.payload.nbytes + blk.scale.nbytes
     return getattr(blk, "nbytes", 0)
 
-# Labeled engine-pass telemetry, now canonically in the obs metrics registry
-# under "engine.passes.<label>". PASS_COUNTS is kept in lockstep as a
-# deprecation shim — existing readers (sweep-resume tests, external scripts)
-# keep seeing the same Counter. reset_pass_counts() scopes a measurement. The
-# lock makes the read-modify-write safe under the sharded executors' D worker
-# threads.
-PASS_COUNTS: "collections.Counter[str]" = collections.Counter()
-_PASS_LOCK = threading.Lock()
+# Labeled engine-pass telemetry lives in the obs metrics registry under
+# "engine.passes.<label>"; reset_pass_counts() scopes a measurement.
 
 
 def _count_pass(label: str) -> None:
     obs.counter(f"engine.passes.{label}").inc()
-    with _PASS_LOCK:
-        PASS_COUNTS[label] += 1
 
 
 def reset_pass_counts() -> None:
     """Zero the engine-pass telemetry (test / measurement scoping)."""
     obs.reset_metrics("engine.passes.")
-    with _PASS_LOCK:
-        PASS_COUNTS.clear()
 
 
 def pass_count(label: str) -> int:
@@ -158,15 +147,10 @@ class BlockPrefetcher:
         # Time spent blocked on an empty queue is THE ingest-bound signal:
         # the producer (host generation / disk / H2D), not the device, is the
         # bottleneck. Accumulated always; a span only when tracing.
-        t0 = time.perf_counter()
-        item = self._q.get()
-        wait = time.perf_counter() - t0
-        self._stall.inc(wait)
-        if obs.TRACER.enabled and wait > 0:
-            s = obs.Span(obs.TRACER, "stall.queue_empty", "stall",
-                         obs.TRACER.current_lane(), {"producer": self.lane})
-            s.t0, s.dur = t0, wait
-            obs.TRACER._record(s)
+        with obs.span("stall.queue_empty", cat="stall", producer=self.lane):
+            t0 = time.perf_counter()
+            item = self._q.get()
+            self._stall.inc(time.perf_counter() - t0)
         if item is _STOP:
             self._done = True
             raise StopIteration
@@ -229,7 +213,13 @@ def map_reduce(
     device: commit blocks (and therefore the map computation) to one specific
     device; None keeps the default-device behaviour.
 
-    label: telemetry tag — each call bumps PASS_COUNTS[label] by one full pass.
+    label: telemetry tag — each call bumps `engine.passes.<label>` by one full
+    pass, and tags the pipelined loop's `block.consume` spans.
+
+    In the pipelined loop each block is one `block.consume` span on the
+    consumer's lane, with children `block.map` (the map dispatch),
+    `block.emit` (the emit callback, where a label fetch syncs) and
+    `block.combine`; the wait on the prefetch queue is outside it.
     """
     _count_pass(label)
     dispatches = obs.counter("engine.map_dispatches")
@@ -258,11 +248,15 @@ def map_reduce(
         acc = init
         try:
             for i, dev in pf:
-                out = map_fn(dev)
-                dispatches.inc()
-                if emit is not None:
-                    emit(i, out)
-                acc = combine_fn(acc, out)
+                with obs.span("block.consume", cat="block", block=i, label=label):
+                    with obs.span("block.map", cat="block"):
+                        out = map_fn(dev)
+                    dispatches.inc()
+                    if emit is not None:
+                        with obs.span("block.emit", cat="block"):
+                            emit(i, out)
+                    with obs.span("block.combine", cat="block"):
+                        acc = combine_fn(acc, out)
         finally:
             pf.close()
     return acc

@@ -15,7 +15,8 @@ batcher keeps its legacy replay log in `.completed` (what the closed-loop
 CLI replay and the property tests read); `replay_log=N` bounds it to the
 last N responses for services that want a tail sample without the callback.
 `.batch_sizes` is always bounded (one 8192-entry ring, mirroring the
-`serve.batch_size` histogram window).
+`serve.batch_size` histogram window). `name` (the served model's, in a
+`ServingTier`) tags the batcher's `serve.flush` spans.
 
 The batcher is thread-safe: `submit` may be called from any number of intake
 threads while flushes run — the pending-queue swap is lock-protected and
@@ -70,6 +71,7 @@ class MicroBatcher:
         clock: Callable[[], float] = time.perf_counter,
         on_result: Callable[[Any, int, float], None] | None = None,
         replay_log: int | None = None,
+        name: str = "default",
     ):
         if max_batch <= 0:
             raise ValueError("max_batch must be positive")
@@ -94,12 +96,14 @@ class MicroBatcher:
         # reorder delivery — batches pop FIFO and deliver before the next pop.
         self._lock = threading.Lock()
         self._flush_lock = threading.Lock()
-        # Rolling service metrics (repro.obs): per-request latency and
-        # per-flush batch size as windowed histograms, live queue depth as a
-        # gauge. Shared registry names, so any co-resident monitor sees them.
-        self._lat = obs.histogram("serve.latency_ms")
+        # Service metrics (repro.obs), bumped once per flush: the batch size
+        # as a windowed histogram, and the summed time the batch's requests
+        # waited in this batcher. Shared registry names, so any co-resident
+        # monitor sees them.
+        self.name = name
+        self._flushes = 0
         self._bs = obs.histogram("serve.batch_size")
-        self._depth = obs.gauge("serve.queue_depth")
+        self._batch_wait = obs.counter("serve.batch_wait_s")
 
     def submit(self, request_id: Any, x) -> None:
         """Enqueue one request; flushes immediately when the batch fills.
@@ -108,7 +112,6 @@ class MicroBatcher:
         with self._lock:
             self._queue.append(p)
             depth = len(self._queue)
-        self._depth.set(depth)
         if depth >= self.max_batch:
             # full batches only: a racing submitter that loses the flush lock
             # must not dispatch the next batch prematurely as a partial one
@@ -144,7 +147,12 @@ class MicroBatcher:
         """Dispatch everything pending, one `max_batch`-bounded batch at a
         time, in queue order. `partial=True` (the default, what deadline and
         drain paths use) dispatches a final short batch; `partial=False`
-        only dispatches full batches (the submit-triggered path)."""
+        only dispatches full batches (the submit-triggered path).
+
+        Each batch is one `serve.flush` span (`flush` sequence number,
+        `rows`, `model`) with children `serve.stack` (stack and cast),
+        `serve.device` (the `process_fn` call) and `serve.deliver` (the
+        per-request callbacks)."""
         with self._flush_lock:
             first = True
             while True:
@@ -154,19 +162,25 @@ class MicroBatcher:
                         break
                     batch = self._queue[: self.max_batch]
                     del self._queue[: self.max_batch]
-                    depth = len(self._queue)
                 first = False
-                self._depth.set(depth)
-                X = np.stack([p.x for p in batch]).astype(np.float32)
-                labels = np.asarray(self.process_fn(X)).astype(np.int32)
-                now = self.clock()
-                for p, lab in zip(batch, labels):
-                    lat = now - p.t_submit
-                    self._lat.observe(lat * 1e3)
-                    if self.on_result is not None:
-                        self.on_result(p.request_id, int(lab), lat)
-                    if self._log_completed:
-                        self.completed.append((p.request_id, int(lab), lat))
+                self._flushes += 1
+                t_flush = self.clock()
+                self._batch_wait.inc(
+                    len(batch) * t_flush - sum(p.t_submit for p in batch))
+                with obs.span("serve.flush", cat="serve", flush=self._flushes,
+                              rows=len(batch), model=self.name):
+                    with obs.span("serve.stack", cat="serve"):
+                        X = np.stack([p.x for p in batch]).astype(np.float32)
+                    with obs.span("serve.device", cat="serve"):
+                        labels = np.asarray(self.process_fn(X)).astype(np.int32)
+                    now = self.clock()
+                    with obs.span("serve.deliver", cat="serve"):
+                        for p, lab in zip(batch, labels):
+                            lat = now - p.t_submit
+                            if self.on_result is not None:
+                                self.on_result(p.request_id, int(lab), lat)
+                            if self._log_completed:
+                                self.completed.append((p.request_id, int(lab), lat))
                 self.batch_sizes.append(len(batch))
                 self._bs.observe(len(batch))
 

@@ -13,7 +13,9 @@ The load-bearing claims:
     inertia trajectory ends at the model's reported inertia, and the exact
     backends (local / stream / stream_shard) report the SAME trajectory from
     the same key — observability must describe one underlying computation;
-  * the PASS_COUNTS shim keeps the legacy engine counter API intact;
+  * every span has an id and its parent's, is mirrored on the profiler's
+    host plane, and the engine, batcher, gc hook and watchdog record the
+    spans the benchmark's per-layer metrics read;
   * the roofline join reports measured/modeled fractions from a synthetic
     dry-run record.
 """
@@ -47,6 +49,13 @@ def _clean_obs():
 # ------------------------------------------------------------------- tracer
 
 
+def _own_spans():
+    """The spans a test made: an enabled tracer also records `gc.collect`
+    and `process.stall` (cat "process") whenever the collector runs or the
+    watchdog wakes late, which a busy test machine makes happen at will."""
+    return [s for s in obs.TRACER.spans() if s.cat != "process"]
+
+
 def test_disabled_span_is_the_null_singleton():
     assert not obs.tracing_enabled()
     s = obs.span("anything", cat="x", attr=1)
@@ -60,7 +69,7 @@ def test_enabled_span_records_duration_and_lane():
     obs.enable_tracing()
     with obs.span("work", cat="test", block=3) as s:
         s.set(rows=100)
-    spans = obs.TRACER.spans()
+    spans = _own_spans()
     assert len(spans) == 1
     (sp,) = spans
     assert sp.name == "work" and sp.cat == "test"
@@ -83,7 +92,7 @@ def test_lanes_are_thread_local():
         t.start()
     for t in threads:
         t.join()
-    assert sorted(s.lane for s in obs.TRACER.spans()) == [
+    assert sorted(s.lane for s in _own_spans()) == [
         "producer:0", "producer:1", "producer:2"]
 
 
@@ -96,7 +105,7 @@ def test_chrome_trace_export_structure(tmp_path):
     d = json.loads(path.read_text())
     events = d["traceEvents"]
     meta = [e for e in events if e["ph"] == "M" and e["name"] == "thread_name"]
-    complete = [e for e in events if e["ph"] == "X"]
+    complete = [e for e in events if e["ph"] == "X" and e["cat"] != "process"]
     assert len(complete) == 2
     named = {(e["pid"], e["tid"]): e["args"]["name"] for e in meta}
     for e in complete:
@@ -114,7 +123,7 @@ def test_chrome_trace_export_structure(tmp_path):
         lanes = check_bench.check_trace(path, min_lanes=1)
     finally:
         sys.path.pop(0)
-    assert lanes == {"main"}
+    assert "main" in lanes and lanes <= {"main", "watchdog"}
 
 
 def test_write_trace_jsonl_suffix(tmp_path):
@@ -126,6 +135,205 @@ def test_write_trace_jsonl_suffix(tmp_path):
     assert len(lines) == 1
     assert lines[0]["name"] == "a" and lines[0]["lane"] == "main"
     assert lines[0]["x"] == 1
+
+
+def test_disabled_span_enters_no_annotation(monkeypatch):
+    from repro.obs import tracer as tracer_mod
+
+    entered = []
+
+    class Probe:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            entered.append(self.name)
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(tracer_mod, "TraceAnnotation", Probe)
+    with obs.span("off") as s:
+        pass
+    assert s is obs.NULL_SPAN and entered == []
+    obs.enable_tracing()
+    with obs.span("on"):
+        pass
+    assert entered == ["on"]  # enabled, the span enters its annotation
+
+
+def test_span_shows_on_the_profilers_host_plane(tmp_path):
+    """Under jax.profiler an obs span is a TraceAnnotation of its name: it
+    lands on the host plane inside the window annotation, on the profile's
+    clock, and its duration agrees with the span's own."""
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        obs.enable_tracing()
+        with jax.profiler.TraceAnnotation("probe.window"):
+            with obs.span("probe.outer"):
+                with obs.span("probe.inner"):
+                    jax.block_until_ready(jax.numpy.ones(8) + 1)
+        obs.disable_tracing()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(tmp_path, "**", "*.xplane.pb"),
+                        recursive=True)
+    host = [ev for plane in ProfileData.from_file(path).planes
+            if plane.name == "/host:CPU"
+            for line in plane.lines for ev in line.events]
+    by_name = {ev.name: ev for ev in host}
+    win, outer, inner = (by_name[n] for n in
+                         ("probe.window", "probe.outer", "probe.inner"))
+    for ev in (outer, inner):
+        assert win.start_ns <= ev.start_ns
+        assert ev.start_ns + ev.duration_ns <= win.start_ns + win.duration_ns
+    assert outer.start_ns <= inner.start_ns
+    spans = {s.name: s for s in obs.TRACER.spans()}
+    assert inner.duration_ns * 1e-9 == pytest.approx(spans["probe.inner"].dur,
+                                                      abs=1e-3)
+
+
+def test_span_ids_and_parents_nest_per_thread():
+    obs.enable_tracing()
+    ready = threading.Barrier(3)
+
+    def worker(tag):
+        obs.set_lane(tag)
+        with obs.span(f"{tag}.outer"):
+            ready.wait(timeout=10)  # every thread has its outer span open
+            with obs.span(f"{tag}.inner"):
+                pass
+
+    with obs.span("main.outer"):
+        threads = [threading.Thread(target=worker, args=(t,)) for t in ("a", "b")]
+        for t in threads:
+            t.start()
+        ready.wait(timeout=10)
+        with obs.span("main.inner"):
+            pass
+        for t in threads:
+            t.join(timeout=10)
+            assert not t.is_alive()
+    spans = {s.name: s for s in _own_spans()}
+    assert len({s.id for s in spans.values()}) == 6
+    for tag in ("main", "a", "b"):
+        assert spans[f"{tag}.outer"].parent is None  # not the other threads'
+        assert spans[f"{tag}.inner"].parent == spans[f"{tag}.outer"].id
+    with obs.span("after"):
+        pass
+    assert obs.TRACER.spans()[-1].parent is None  # the stacks emptied
+
+
+def test_map_reduce_records_block_spans():
+    import jax.numpy as jnp
+
+    from repro.stream import engine
+
+    store = gaussian_blobs_blocks(0, 512, 4, 2, block_rows=128)[0]
+    emitted = []
+    obs.enable_tracing()
+    total = engine.map_reduce(store, lambda x: x.sum(), lambda a, b: a + b,
+                              jnp.asarray(0.0), label="probe",
+                              emit=lambda i, out: emitted.append(float(out)))
+    spans = obs.TRACER.spans()
+    consume = [s for s in spans if s.name == "block.consume"]
+    assert [s.attrs for s in consume] == [
+        {"block": i, "label": "probe"} for i in range(store.num_blocks)]
+    (pass_span,) = [s for s in spans if s.name == "pass.probe"]
+    for c in consume:
+        assert c.parent == pass_span.id
+        kids = sorted(s.name for s in spans if s.parent == c.id)
+        assert kids == ["block.combine", "block.emit", "block.map"]
+        assert sum(s.dur for s in spans if s.parent == c.id) <= c.dur
+    waits = [s for s in spans if s.name == "stall.queue_empty"]
+    assert waits and all(s.parent == pass_span.id for s in waits)
+    assert float(total) == pytest.approx(sum(emitted), rel=1e-5)
+
+
+def test_serving_flush_spans_and_wait_counters():
+    from repro.serving import ModelRegistry, ServingTier
+    from repro.stream.microbatch import MicroBatcher
+
+    obs.enable_tracing()
+    before = obs.snapshot("serve.")
+    mb = MicroBatcher(lambda X: np.zeros(X.shape[0], np.int32), max_batch=4,
+                      name="probe")
+    for i in range(10):
+        mb.submit(i, np.zeros(3, np.float32))
+    mb.drain()
+    spans = obs.TRACER.spans()
+    flushes = [s for s in spans if s.name == "serve.flush"]
+    assert [s.attrs["flush"] for s in flushes] == [1, 2, 3]
+    assert sum(s.attrs["rows"] for s in flushes) == 10
+    assert {s.attrs["model"] for s in flushes} == {"probe"}
+    for f in flushes:
+        kids = sorted(s.name for s in spans if s.parent == f.id)
+        assert kids == ["serve.deliver", "serve.device", "serve.stack"]
+
+    obs.clear_trace()
+    reg = ModelRegistry(max_batch=4)
+    reg.register("echo", lambda X: np.zeros(len(X), np.int32), d=3)
+    with ServingTier(reg) as tier:
+        futs = [tier.submit(i, np.zeros(3, np.float32), model="echo")
+                for i in range(10)]
+        assert all(f.result(timeout=10).ok for f in futs)
+    rows = [s.attrs["rows"] for s in obs.TRACER.spans() if s.name == "serve.flush"]
+    assert sum(rows) == 10
+    seen = obs.delta(before, obs.snapshot("serve."))
+    assert seen["serve.batch_wait_s"] > 0
+    assert seen["serve.intake_wait_s"] > 0
+
+
+def test_gc_hook_records_collections_and_leaves_callbacks_as_found():
+    import gc
+
+    found = list(gc.callbacks)
+    before = obs.counter("process.gc_pause_s").value
+    obs.enable_tracing()
+    assert len(gc.callbacks) == len(found) + 1
+    with obs.span("outer") as outer:
+        gc.collect()
+    obs.disable_tracing()
+    assert gc.callbacks == found
+    (col,) = [s for s in obs.TRACER.spans()
+              if s.name == "gc.collect" and s.attrs["generation"] == 2]
+    assert col.parent == outer.id and col.attrs["collected"] >= 0
+    assert obs.counter("process.gc_pause_s").value - before >= col.dur > 0
+    recorded = len(obs.TRACER.spans())
+    gc.collect()  # with tracing off, the hook is gone
+    assert len(obs.TRACER.spans()) == recorded
+
+
+def test_watchdog_records_an_injected_late_wakeup():
+    from repro.obs.tracer import WATCHDOG_PERIOD_S, ProcessWatch, Tracer
+
+    tracer = Tracer()
+    tracer.enabled = True  # spans on, no real watchdog or gc hook
+    now = [100.0]
+    calls = []
+
+    def sleep(s):
+        calls.append(s)
+        now[0] += s + (0.050 if len(calls) == 3 else 0.001)
+        if len(calls) == 6:
+            watch._halt.set()
+
+    watch = ProcessWatch(tracer, clock=lambda: now[0], sleep=sleep)
+    watch.run()
+    (stall,) = tracer.spans()
+    assert stall.name == "process.stall" and stall.lane == "watchdog"
+    assert stall.attrs["late_ms"] == pytest.approx(50.0)
+    assert stall.dur == pytest.approx(0.050)
+    # from its due time: two on-time wake-ups of period + 1 ms before it
+    assert stall.t0 == pytest.approx(100.0 + 3 * WATCHDOG_PERIOD_S + 0.002)
 
 
 # ------------------------------------------------------------------ metrics
@@ -188,26 +396,6 @@ def test_counter_thread_safety():
     for t in threads:
         t.join()
     assert c.value == N * T  # no lost updates under concurrent writers
-
-
-# ---------------------------------------------------------- PASS_COUNTS shim
-
-
-def test_pass_counts_shim_stays_in_lockstep():
-    from repro.stream import engine
-
-    engine.reset_pass_counts()
-    store = gaussian_blobs_blocks(0, 512, 4, 2, block_rows=128)[0]
-    import jax.numpy as jnp
-
-    engine.map_reduce(store, lambda x: x.sum(), lambda a, b: a + b,
-                      jnp.asarray(0.0), label="shim_probe")
-    assert engine.pass_count("shim_probe") == 1
-    assert engine.PASS_COUNTS["shim_probe"] == 1  # legacy dict still served
-    assert obs.counter("engine.passes.shim_probe").value == 1
-    engine.reset_pass_counts()
-    assert engine.pass_count("shim_probe") == 0
-    assert engine.PASS_COUNTS["shim_probe"] == 0
 
 
 # ---------------------------------------------------------------- FitReport
@@ -341,8 +529,8 @@ def test_microbatcher_feeds_serve_metrics():
         mb.submit(i, np.zeros(3, np.float32))
     mb.drain()
     snap = obs.snapshot("serve.")
-    assert snap["serve.latency_ms"]["count"] == 10
     assert snap["serve.batch_size"]["count"] == 3  # 4 + 4 + 2
+    assert snap["serve.batch_size"]["sum"] == 10
     assert snap["serve.batch_size"]["max"] == 4
-    assert obs.gauge("serve.queue_depth").value == 0  # drained
-    assert obs.gauge("serve.queue_depth").hwm >= 3
+    assert snap["serve.batch_wait_s"] > 0
+    assert "serve.latency_ms" not in snap and "serve.queue_depth" not in snap
