@@ -557,7 +557,7 @@ class KernelKMeans:
 
             def _emit(i, out):
                 lo = X.row_offset(i)
-                labels[lo:lo + out.shape[0]] = np.asarray(out, np.int32)
+                labels[lo:lo + out.shape[0]] = out
 
             map_reduce(
                 X,

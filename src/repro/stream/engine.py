@@ -14,6 +14,15 @@ degrades to the fully synchronous one-block-at-a-time baseline (get, transfer,
 compute, block_until_ready), which `benchmarks/stream_bench.py` uses as the
 overlap reference.
 
+Deferred emit: `emit` never waits on the device. After block i's map is
+dispatched the engine starts an asynchronous device-to-host copy of the part
+of its output that `emit` reads (`emit_pick`, e.g. a Lloyd pass's labels),
+and hands that host copy to `emit(i, ...)` only after block i+L has been
+dispatched, L being the prefetch depth. By then the copy has landed, so the
+consumer thread keeps queuing blocks on the device instead of waiting out a
+round trip per block. At most L outputs are pending per consumer, which keeps
+device memory O(block) and bounds how far the host runs ahead of the device.
+
 Device placement: `device=` commits every produced block to one specific
 device instead of the default. This is the per-device-queue building block of
 the sharded executor (`repro.stream.sharded`): each device of a mesh gets its
@@ -22,13 +31,13 @@ devices concurrently — D mappers pulling their own HDFS blocks.
 """
 from __future__ import annotations
 
+import collections
 import queue
 import threading
 import time
 from typing import Any, Callable
 
 import jax
-import numpy as np
 
 from repro import obs
 from repro.stream.blockstore import BlockStore, EncodedBlock, WritableBlockStore
@@ -185,6 +194,14 @@ class BlockPrefetcher:
         self._done = True
 
 
+def _start_host_copy(tree) -> None:
+    """Start the device-to-host copy of every device array in `tree`; the
+    later `jax.device_get` then reads the landed host value."""
+    for leaf in jax.tree.leaves(tree):
+        if isinstance(leaf, jax.Array):
+            leaf.copy_to_host_async()
+
+
 def map_reduce(
     store: BlockStore,
     map_fn: Callable[[Any], Any],
@@ -193,6 +210,7 @@ def map_reduce(
     *,
     prefetch: int = 2,
     emit: Callable[[int, Any], None] | None = None,
+    emit_pick: Callable[[Any], Any] | None = None,
     device=None,
     label: str = "map_reduce",
 ) -> Any:
@@ -202,13 +220,26 @@ def map_reduce(
     associative-enough that per-block accumulation matches the monolithic
     computation (sums, counts, min/max — the paper's (Z, g) case).
 
-    emit(i, out), when given, receives each block's map output *before* the
-    combine — used to spill per-block results (labels, embeddings) back to a
-    host store. The emit callback runs on the consumer thread in block order.
+    emit(i, host), when given, receives `emit_pick(out)` of each block's map
+    output as host (numpy) arrays — used to spill per-block results (labels,
+    embeddings) back to a host store. `emit_pick` selects the leaves `emit`
+    reads (default: the whole output); only those are copied to the host, the
+    rest stays on the device for the combine. Every block is emitted exactly
+    once, in block order, on the consumer thread, and all emits have run when
+    map_reduce returns.
 
-    prefetch: depth of the producer queue. 0 = synchronous baseline: every
-    block is fetched, transferred, computed and *waited on* before the next
-    block is touched.
+    Deferred emit (pipelined loop): after block i's map is dispatched, the
+    copy of its picked leaves starts asynchronously, and `emit(i, ...)` runs
+    only once block i+prefetch has been dispatched, or at the end of the pass,
+    which drains what is pending in block order. So the consumer never waits
+    on the device for a block it just dispatched; at most `prefetch` picked
+    outputs are pending. Counters: `engine.emits_deferred` (emits handed over
+    after their asynchronous copy) and `engine.emit_wait_s` (time the host
+    fetch of a deferred emit still took, i.e. the copy had not landed).
+
+    prefetch: depth of the producer queue and of the pending emits. 0 =
+    synchronous baseline: every block is fetched, transferred, computed,
+    emitted and *waited on* before the next block is touched.
 
     device: commit blocks (and therefore the map computation) to one specific
     device; None keeps the default-device behaviour.
@@ -218,11 +249,14 @@ def map_reduce(
 
     In the pipelined loop each block is one `block.consume` span on the
     consumer's lane, with children `block.map` (the map dispatch),
-    `block.emit` (the emit callback, where a label fetch syncs) and
-    `block.combine`; the wait on the prefetch queue is outside it.
+    `block.combine`, and the `block.emit` (attr `block`: the emitted block,
+    an earlier one; host fetch plus callback) of each deferred emit that runs
+    in it; the last block's span holds the end-of-pass drain. The wait on the
+    prefetch queue is outside it.
     """
     _count_pass(label)
     dispatches = obs.counter("engine.map_dispatches")
+    pick = emit_pick if emit_pick is not None else (lambda out: out)
     if prefetch <= 0:
         blocks = obs.counter("engine.blocks_read")
         nbytes = obs.counter("engine.bytes_h2d")
@@ -237,10 +271,24 @@ def map_reduce(
                 out = map_fn(dev)
                 dispatches.inc()
                 if emit is not None:
-                    emit(i, out)
+                    emit(i, jax.device_get(pick(out)))
                 acc = combine_fn(acc, out)
                 jax.block_until_ready(acc)
         return acc
+
+    deferred = obs.counter("engine.emits_deferred")
+    emit_wait = obs.counter("engine.emit_wait_s")
+    pending: collections.deque = collections.deque()
+    last = store.num_blocks - 1
+
+    def emit_oldest() -> None:
+        j, picked = pending.popleft()
+        with obs.span("block.emit", cat="block", block=j):
+            t0 = time.perf_counter()
+            host = jax.device_get(picked)
+            emit_wait.inc(time.perf_counter() - t0)
+            deferred.inc()
+            emit(j, host)
 
     with obs.span(f"pass.{label}", cat="pass", blocks=store.num_blocks,
                   prefetch=prefetch):
@@ -253,10 +301,13 @@ def map_reduce(
                         out = map_fn(dev)
                     dispatches.inc()
                     if emit is not None:
-                        with obs.span("block.emit", cat="block"):
-                            emit(i, out)
+                        picked = pick(out)
+                        _start_host_copy(picked)
+                        pending.append((i, picked))
                     with obs.span("block.combine", cat="block"):
                         acc = combine_fn(acc, out)
+                    while pending and (len(pending) > prefetch or i == last):
+                        emit_oldest()
         finally:
             pf.close()
     return acc
@@ -300,7 +351,7 @@ def cache_embedding(
 
     def emit(i, y):
         gid = store.block_id(i)
-        out.put(gid, np.asarray(y))
+        out.put(gid, y)
         if sized:
             bytes_staged.inc(out.staged_nbytes(gid))
 

@@ -25,6 +25,7 @@ the old `use_pallas=` keyword is a deprecated alias. These drivers back the
 """
 from __future__ import annotations
 
+from operator import itemgetter
 from typing import NamedTuple
 
 import jax
@@ -62,8 +63,8 @@ def _block_map(coeffs, discrepancy, centroids_cell, pol: ComputePolicy):
     """(Z, g, labels, cost) map for one block, built from the ONE
     `ops.lloyd_step_plan` every backend shares: X-mode when coeffs given
     (embed fused into the step — one Pallas dispatch for fusable members under
-    a Pallas policy), Y-mode otherwise. Labels stay at index 2 (emit callbacks
-    read out[2]); the trailing cost is the block's inertia under the SAME
+    a Pallas policy), Y-mode otherwise. Labels stay at index 2 (the label emits
+    pick out[2]); the trailing cost is the block's inertia under the SAME
     centroids. `centroids_cell` is a 1-element list so minibatch can swap
     centroids between blocks without retracing."""
     plan = ops.lloyd_step_plan(params=coeffs, discrepancy=discrepancy, policy=pol)
@@ -188,9 +189,8 @@ def ooc_lloyd(
     labels_host = np.full(store.n, -1, dtype=np.int32)
     changed_cell = [True]
 
-    def emit(i, out):
+    def emit(i, new):
         lo = store.row_offset(i)
-        new = np.asarray(out[2], dtype=np.int32)
         sl = labels_host[lo:lo + new.shape[0]]
         if not changed_cell[0] and not np.array_equal(new, sl):
             changed_cell[0] = True
@@ -224,7 +224,7 @@ def ooc_lloyd(
             Z, g, cost = map_reduce(
                 store, map_fn,
                 lambda acc, out: (acc[0] + out[0], acc[1] + out[1], acc[2] + out[3]),
-                zero, prefetch=prefetch, emit=emit,
+                zero, prefetch=prefetch, emit=emit, emit_pick=itemgetter(2),
             )
             new_c = centroid_update(Z, g, centroids_cell[0])
             shift = float(jnp.linalg.norm(new_c - centroids_cell[0]))
@@ -262,13 +262,13 @@ def _final_assign(store, coeffs, disc, centroids_cell, labels_host, prefetch, po
     pass (labels at index 0, cost at 1 — the final-pass convention)."""
     plan = ops.lloyd_step_plan(params=coeffs, discrepancy=disc, policy=pol)
 
-    def emit(i, out):
+    def emit(i, lab):
         lo = store.row_offset(i)
-        labels_host[lo:lo + out[0].shape[0]] = np.asarray(out[0], dtype=np.int32)
+        labels_host[lo:lo + lab.shape[0]] = lab
 
     inertia = map_reduce(
         store, plan.assign_map(centroids_cell), lambda acc, out: acc + out[1],
-        jnp.asarray(0.0), prefetch=prefetch, emit=emit,
+        jnp.asarray(0.0), prefetch=prefetch, emit=emit, emit_pick=itemgetter(0),
     )
     return float(inertia)
 
@@ -333,9 +333,9 @@ def minibatch_lloyd(
     state = [jnp.zeros((k, m), jnp.float32), jnp.zeros((k,), jnp.float32),
              jnp.zeros((), jnp.float32)]
 
-    def emit(i, out):
+    def emit(i, lab):
         lo = store.row_offset(i)
-        labels_host[lo:lo + out[2].shape[0]] = np.asarray(out[2], dtype=np.int32)
+        labels_host[lo:lo + lab.shape[0]] = lab
 
     def combine(acc, out):
         state[0], state[1], state[2], centroids_cell[0] = fold(
@@ -370,7 +370,8 @@ def minibatch_lloyd(
             seen_cost = float(state[2])
     for ep in range(start_ep, epochs):
         with obs.span("lloyd.epoch", cat="lloyd", epoch=ep) as sp:
-            map_reduce(store, map_fn, combine, None, prefetch=prefetch, emit=emit)
+            map_reduce(store, map_fn, combine, None, prefetch=prefetch,
+                       emit=emit, emit_pick=itemgetter(2))
             total = float(state[2])
             trajectory.append(total - seen_cost)
             seen_cost = total

@@ -29,6 +29,7 @@ from __future__ import annotations
 
 import threading
 from functools import lru_cache
+from operator import itemgetter
 from typing import Any, Callable, Sequence
 
 import jax
@@ -69,11 +70,13 @@ def sharded_map_reduce(
     devices: Sequence,
     prefetch: int = 2,
     emits: Sequence[Callable[[int, Any], None] | None] | None = None,
+    emit_pick: Callable[[Any], Any] | None = None,
 ) -> list:
     """One free-running `map_reduce` per device, concurrently: device d
     streams `shards[d]` through its own producer queue (blocks committed to
     `devices[d]`), folds its own accumulator with `combine_fn`, and calls its
-    own `emits[d]` in local block order. Returns the per-device accumulators
+    own `emits[d]` in local block order, on the host arrays `emit_pick`
+    selects (the engine's deferred emit). Returns the per-device accumulators
     — the caller owns the cross-device reduction (`cross_device_sum`).
 
     `map_fns[d]` must keep its inputs on `devices[d]` (close over
@@ -93,7 +96,7 @@ def sharded_map_reduce(
             accs[d] = map_reduce(
                 shards[d], map_fns[d], combine_fn, inits[d],
                 prefetch=prefetch, emit=emits[d] if emits is not None else None,
-                device=devices[d],
+                emit_pick=emit_pick, device=devices[d],
             )
         except BaseException as e:  # noqa: BLE001 - re-raised on the caller
             errs[d] = e
@@ -317,9 +320,8 @@ def _final_assign_pool(store, coeffs_d, disc, c_locals, labels_host, pol,
 
 def _label_emits(shards, labels_host, changed=None):
     def make(shard):
-        def emit(i, out):
+        def emit(i, new):
             lo = shard.row_offset(i)
-            new = np.asarray(out[2], dtype=np.int32)
             if changed is not None and not changed[0] \
                     and not np.array_equal(new, labels_host[lo:lo + new.shape[0]]):
                 changed[0] = True
@@ -338,9 +340,8 @@ def _final_assign_sharded(
     fns = _assign_map_fns(coeffs_d, disc, c_locals, pol, devices)
 
     def emit_of(shard):
-        def emit(i, out):
+        def emit(i, lab):
             lo = shard.row_offset(i)
-            lab = np.asarray(out[0], dtype=np.int32)
             labels_host[lo:lo + lab.shape[0]] = lab
 
         return emit
@@ -349,6 +350,7 @@ def _final_assign_sharded(
     costs = sharded_map_reduce(
         shards, fns, lambda acc, out: acc + out[1], zeros,
         devices=devices, prefetch=prefetch, emits=[emit_of(s) for s in shards],
+        emit_pick=itemgetter(0),
     )
     return float(sum(float(c) for c in costs))
 
@@ -464,7 +466,7 @@ def ooc_lloyd_sharded(
                     lambda acc, out: (acc[0] + out[0], acc[1] + out[1],
                                       acc[2] + out[3]),
                     list(zeros_d), devices=devices, prefetch=prefetch,
-                    emits=emits,
+                    emits=emits, emit_pick=itemgetter(2),
                 )
                 # s-step sync rule: always at s-boundaries, and always on the
                 # LAST iteration (cap reached or labels fixed) so the loop

@@ -116,16 +116,22 @@ def _zeros_like_stats(inits: Sequence[Array], active: Sequence[int]):
     ]
 
 
-def _label_writer(labels, converged, changed, k_indices, lab_index=2):
-    """Emit callback factory: write each candidate's block labels at `lo` and
-    flag changes against the previously stored pass (ooc_lloyd's criterion,
-    per candidate). `lab_index` locates labels in the per-k map output
-    (position 2 in the (Z, g, labels) stats tuple, 0 in the final-pass
-    (labels, cost) pair)."""
+def _pick_labels(lab_index):
+    """emit_pick for the per-k list of map outputs: each k's labels, at
+    position 2 in the (Z, g, labels) stats tuple, 0 in the final-pass
+    (labels, cost) pair. Only these are copied to the host."""
+    return lambda outs: [o[lab_index] for o in outs]
 
-    def write(lo, outs):
+
+def _label_writer(labels, converged, changed, k_indices):
+    """Emit callback factory: write each candidate's block labels (host
+    arrays, one (R, rows) stack per k, as `_pick_labels` selects them) at
+    `lo` and flag changes against the previously stored pass (ooc_lloyd's
+    criterion, per candidate)."""
+
+    def write(lo, labs):
         for j, i in enumerate(k_indices):
-            lab = np.asarray(outs[j][lab_index], dtype=np.int32)
+            lab = labs[j]
             for r in range(lab.shape[0]):
                 if converged is not None and converged[i, r]:
                     continue
@@ -205,8 +211,8 @@ def sweep_lloyd(
         stats = map_reduce(
             y_store, map_fn, combine, _zeros_like_stats(cents, active),
             prefetch=prefetch,
-            emit=lambda i, outs: write(y_store.row_offset(i), outs),
-            label="sweep_lloyd",
+            emit=lambda i, labs: write(y_store.row_offset(i), labs),
+            emit_pick=_pick_labels(2), label="sweep_lloyd",
         )
         active = _advance(
             cents, cents, active, stats, converged, changed, iters_run
@@ -215,7 +221,7 @@ def sweep_lloyd(
 
     # Final pass under the final centroids: authoritative labels + inertia
     # for EVERY candidate (mirrors lloyd._final_assign).
-    write_final = _label_writer(labels, None, None, list(range(K)), lab_index=0)
+    write_final = _label_writer(labels, None, None, list(range(K)))
 
     def final_fn(y):
         return [
@@ -228,8 +234,8 @@ def sweep_lloyd(
         lambda acc, outs: [a + o[1] for a, o in zip(acc, outs)],
         [jnp.zeros((R_of[i],), jnp.float32) for i in range(K)],
         prefetch=prefetch,
-        emit=lambda i, outs: write_final(y_store.row_offset(i), outs),
-        label="sweep_lloyd",
+        emit=lambda i, labs: write_final(y_store.row_offset(i), labs),
+        emit_pick=_pick_labels(0), label="sweep_lloyd",
     )
     inertia = np.stack([np.asarray(c, dtype=np.float64) for c in costs])
     return SweepLloydOut(labels, cents, inertia, iters_run, passes)
@@ -306,10 +312,11 @@ def sweep_lloyd_sharded(
             shards, [make_map(d) for d in range(D)], combine, zeros_d,
             devices=devices, prefetch=prefetch,
             emits=[
-                (lambda i, outs, s=shards[d], w=writers[d]:
-                 w(s.row_offset(i), outs))
+                (lambda i, labs, s=shards[d], w=writers[d]:
+                 w(s.row_offset(i), labs))
                 for d in range(D)
             ],
+            emit_pick=_pick_labels(2),
         )
         reduced = cross_device_sum(accs, devices)
         active = _advance(
@@ -321,7 +328,7 @@ def sweep_lloyd_sharded(
     # device summed on the host (the last tiny shuffle).
     cells = device_cells(list(range(K)))
     final_writers = [
-        _label_writer(labels, None, None, list(range(K)), lab_index=0)
+        _label_writer(labels, None, None, list(range(K)))
         for _ in range(D)
     ]
 
@@ -345,10 +352,11 @@ def sweep_lloyd_sharded(
         lambda acc, outs: [a + o[1] for a, o in zip(acc, outs)],
         zeros_d, devices=devices, prefetch=prefetch,
         emits=[
-            (lambda i, outs, s=shards[d], w=final_writers[d]:
-             w(s.row_offset(i), outs))
+            (lambda i, labs, s=shards[d], w=final_writers[d]:
+             w(s.row_offset(i), labs))
             for d in range(D)
         ],
+        emit_pick=_pick_labels(0),
     )
     inertia = np.stack([
         np.sum([np.asarray(costs[d][i], dtype=np.float64) for d in range(D)],

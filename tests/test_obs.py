@@ -248,11 +248,21 @@ def test_map_reduce_records_block_spans():
     assert [s.attrs for s in consume] == [
         {"block": i, "label": "probe"} for i in range(store.num_blocks)]
     (pass_span,) = [s for s in spans if s.name == "pass.probe"]
+    # Deferred emit: block i is emitted inside the consume of block
+    # i + prefetch (default 2), and the last consume drains the rest.
+    emitted_in = {}
     for c in consume:
         assert c.parent == pass_span.id
-        kids = sorted(s.name for s in spans if s.parent == c.id)
-        assert kids == ["block.combine", "block.emit", "block.map"]
-        assert sum(s.dur for s in spans if s.parent == c.id) <= c.dur
+        kids = [s for s in spans if s.parent == c.id]
+        assert sorted(s.name for s in kids if s.name != "block.emit") == [
+            "block.combine", "block.map"]
+        emitted_in[c.attrs["block"]] = [
+            s.attrs["block"] for s in kids if s.name == "block.emit"]
+        assert sum(s.dur for s in kids) <= c.dur
+    assert emitted_in == {0: [], 1: [], 2: [0], 3: [1, 2, 3]}
+    emits = [s for s in spans if s.name == "block.emit"]
+    assert sorted(s.attrs["block"] for s in emits) == list(range(store.num_blocks))
+    assert {s.parent for s in emits} <= {c.id for c in consume}
     waits = [s for s in spans if s.name == "stall.queue_empty"]
     assert waits and all(s.parent == pass_span.id for s in waits)
     assert float(total) == pytest.approx(sum(emitted), rel=1e-5)

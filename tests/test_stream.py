@@ -9,11 +9,15 @@ The load-bearing claims:
   * the micro-batcher preserves request order and matches core.kkmeans.predict;
   * the clustering checkpoint round-trips (coeffs, centroids).
 """
+import threading
+from operator import itemgetter
+
 import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
 
+from repro import obs
 from repro.core.apnc import embed
 from repro.core.kernels_fn import Kernel
 from repro.core.kkmeans import APNCConfig, fit_coefficients
@@ -130,6 +134,81 @@ def test_map_reduce_propagates_producer_errors():
         map_reduce(store, lambda x: x, lambda a, b: b, None, prefetch=2)
 
 
+def _tail_store():
+    """Six blocks of 128 rows, the last a short tail of 60."""
+    store, _ = gaussian_blobs_blocks(4, 700, 5, 3, block_rows=128)
+    assert store.rows_of(store.num_blocks - 1) == 60
+    return store
+
+
+def _producer_threads():
+    return {t for t in threading.enumerate() if t.name.startswith("block-")}
+
+
+@pytest.mark.parametrize("prefetch", [0, 1, 2, 4])
+def test_map_reduce_deferred_emit_sees_every_block_once_in_order(prefetch):
+    """Deferred emit: each block is handed to emit exactly once, in block
+    order, on the calling thread, as host arrays holding what the
+    synchronous path emits, and all before map_reduce returns."""
+    store = _tail_store()
+    fn = jax.jit(lambda x: (jnp.sum(x, axis=0), x[:, 0] * 2.0))
+    seen = []
+
+    def emit(i, col):
+        assert threading.current_thread() is threading.main_thread()
+        assert isinstance(col, np.ndarray)
+        seen.append((i, col.copy()))
+
+    total = map_reduce(
+        store, fn, lambda a, out: a + out[0], jnp.zeros(5),
+        prefetch=prefetch, emit=emit, emit_pick=itemgetter(1),
+    )
+    assert [i for i, _ in seen] == list(range(store.num_blocks))
+    for i, col in seen:
+        np.testing.assert_array_equal(col, np.asarray(fn(store.get(i))[1]))
+    assert seen[-1][1].shape == (60,)
+    np.testing.assert_allclose(
+        np.asarray(total), store.materialize().sum(axis=0), rtol=1e-5)
+
+
+@pytest.mark.parametrize("where", ["emit", "map"])
+def test_map_reduce_error_at_block_propagates_and_joins_producer(where):
+    store = _tail_store()
+    j = 3
+    before = _producer_threads()
+    seen = []
+
+    def map_fn(x):
+        if where == "map" and len(dispatched) == j:
+            raise RuntimeError(f"map failed at {j}")
+        dispatched.append(1)
+        return jnp.sum(x)
+
+    def emit(i, out):
+        if where == "emit" and i == j:
+            raise RuntimeError(f"emit failed at {j}")
+        seen.append(i)
+
+    dispatched: list = []
+    with pytest.raises(RuntimeError, match=f"{where} failed at {j}"):
+        map_reduce(store, map_fn, lambda a, b: a + b, jnp.asarray(0.0),
+                   prefetch=2, emit=emit)
+    assert _producer_threads() <= before, "producer thread left running"
+    assert seen == list(range(len(seen))) and len(seen) <= j
+
+
+@pytest.mark.parametrize("prefetch", [0, 2])
+def test_map_reduce_counts_deferred_emits(prefetch):
+    store = _tail_store()
+    before = obs.snapshot("engine.")
+    map_reduce(store, jax.jit(lambda x: x[:, 0]), lambda a, _: a, None,
+               prefetch=prefetch, emit=lambda i, _: None)
+    seen = obs.delta(before, obs.snapshot("engine."))
+    assert seen.get("engine.emits_deferred", 0) == (
+        store.num_blocks if prefetch else 0)
+    assert seen.get("engine.emit_wait_s", 0.0) >= 0.0
+
+
 # ---------------------------------------------------------------- reservoir
 
 
@@ -193,6 +272,31 @@ def test_ooc_lloyd_block_size_invariance():
         if labels is None:
             labels = res.labels
         assert np.array_equal(res.labels, labels), f"block_rows={br} diverged"
+
+
+def _assert_same_fit(a, b):
+    assert np.array_equal(a.labels, b.labels)
+    assert np.array_equal(np.asarray(a.centroids), np.asarray(b.centroids))
+    assert a.iters == b.iters and a.inertia == b.inertia
+    assert a.trajectory == b.trajectory and a.shifts == b.shifts
+
+
+def test_deferred_emit_leaves_lloyd_results_unchanged():
+    """Exact and mini-batch Lloyd give bitwise the same fit with the emit
+    deferred (prefetch 2) as with the synchronous loop (prefetch 0), and the
+    exact driver's early stop lands on the same iteration."""
+    X, _, coeffs = _fit_rings(n=500)
+    Y = embed(X, coeffs)
+    init = kmeanspp_init(jax.random.PRNGKey(3), Y, 2, coeffs.discrepancy)
+    store = BlockStore.from_array(np.asarray(X), 96)  # short tail block
+    iters = 30
+    exact = [ooc_lloyd(store, 2, coeffs=coeffs, iters=iters, init=init,
+                       prefetch=p) for p in (0, 2)]
+    _assert_same_fit(*exact)
+    assert exact[0].iters < iters, "the fit must stop on unchanged labels"
+    mb = [minibatch_lloyd(store, 2, coeffs=coeffs, init=init, epochs=2,
+                          prefetch=p) for p in (0, 2)]
+    _assert_same_fit(*mb)
 
 
 def test_stream_embed_sharded_blocks_land_at_global_offsets():

@@ -230,6 +230,25 @@ def test_ooc_lloyd_mesh_kwarg_and_arg_validation():
                   devices=DEVICES, mesh=_mesh())
 
 
+def test_sharded_lockstep_fit_unchanged_by_deferred_emit():
+    """The lockstep driver's label emits, deferred (prefetch 2) or
+    synchronous (prefetch 0), give bitwise the same fit and the same early
+    stop."""
+    store, _ = gaussian_blobs_blocks(6, 1500, 6, 3, block_rows=128)
+    coeffs = _fit_blob_coeffs(store)
+    from repro.core.lloyd import kmeanspp_init
+
+    pool = jnp.asarray(stream_embed(store, coeffs).materialize()[:512])
+    init = kmeanspp_init(jax.random.PRNGKey(2), pool, 3, coeffs.discrepancy)
+    fits = [ooc_lloyd(store, 3, coeffs=coeffs, iters=40, init=init,
+                      prefetch=p, devices=DEVICES) for p in (0, 2)]
+    a, b = fits
+    assert np.array_equal(a.labels, b.labels)
+    assert np.array_equal(np.asarray(a.centroids), np.asarray(b.centroids))
+    assert a.iters == b.iters < 40
+    assert a.inertia == b.inertia and a.trajectory == b.trajectory
+
+
 def test_minibatch_sharded_quality_and_coverage():
     """Sharded mini-batch applies one decayed update per round of D blocks —
     a different (approximate) trajectory than the single-device driver, so
