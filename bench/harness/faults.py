@@ -27,15 +27,22 @@ class Patcher:
 
 
 def state_unchanged(mp):
-    """Every Lloyd update returns the centroids it was given."""
+    """Every Lloyd update returns the centroids it was given: the stream
+    drivers' and the resident driver's (`core.lloyd.lloyd`)."""
+    import importlib
+
     import repro.stream.lloyd as lloyd
     import repro.stream.sharded as sharded
+
+    # the module, not the `lloyd` function that repro.core exports by its name
+    core_lloyd = importlib.import_module("repro.core.lloyd")
 
     def keep(Z, g, prev):
         return prev
 
     mp.setattr(lloyd, "centroid_update", keep)
     mp.setattr(sharded, "centroid_update", keep)
+    mp.setattr(core_lloyd, "centroid_update", keep)
 
 
 def half_batch(mp):

@@ -1,11 +1,21 @@
 """Traffic kind "fit": whole fits back to back through `KernelKMeans.fit`.
 
-Set-up makes the configuration's rows once in host RAM, wraps them as the
-`BlockStore` a deployment streams from, and warms every program a fit runs
-by one whole fit on a small store with the same block shapes (the same
-full-block and tail-block rows, on the same devices). The window then runs
-fits, each with its own key from `--seed`, until `--seconds` have passed. A
-fit still running at the deadline finishes but is dropped.
+Set-up makes the configuration's rows once in host RAM, in the row format
+the configuration names (`rows`, default "mixture": spec.rows_module), and
+holds them where the traffic says (`hold`):
+
+  "host" (the default)  a `BlockStore` that a deployment streams from. Every
+                        program a fit runs is warmed by one whole fit on a
+                        small store with the same block shapes (the same
+                        full-block and tail-block rows, on the same devices).
+  "device"              the whole X placed in device memory once. Every fit
+                        of the window gets that array; the warm-up is one
+                        whole fit on it, since the resident driver's
+                        programs are shaped by the whole n.
+
+The window then runs fits, each with its own key from `--seed`, until
+`--seconds` have passed. A fit still running at the deadline finishes but is
+dropped.
 
 fit_rows_per_s counts n rows for every pass a completed fit made: phase 1's
 reservoir pass, each Lloyd iteration and the final assignment pass (the
@@ -16,10 +26,9 @@ After the window a sample of its fits, drawn from the seed, is held to the
 plain reference. A fit's centroids must be the mean of its labels only at a
 fixed point (no label changed). A fit stopped at the `iters` cap returns
 centroids one update behind its labels, so its centroid update is checked by
-one more: the program's own out-of-core Lloyd driver (the one the fit ran,
-with the fit's policy, store and compiled step) runs one iteration from the
-returned centroids, and what it returns must be the mean of the returned
-labels.
+one more: the program's own Lloyd driver, the one the fit ran, with the
+fit's policy, input and compiled step, runs one iteration from the returned
+centroids, and what it returns must be the mean of the returned labels.
 """
 from __future__ import annotations
 
@@ -27,9 +36,10 @@ import dataclasses
 import time
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
-from . import data
+from . import data, spec
 from .reference import FitAnswer, fit_numbers, score_rows
 from .spec import Outcome
 
@@ -90,10 +100,13 @@ def answer(est, index: int, key_seed: int) -> FitAnswer:
     )
 
 
-def next_centroids(template, store, a: FitAnswer) -> np.ndarray:
+def next_centroids(template, fit_input, a: FitAnswer) -> np.ndarray:
     """The program's next Lloyd update of a fit's returned state: one
-    iteration of the out-of-core driver that `template` (an estimator made
-    as the fit's was) runs, on `store`, from the returned centroids."""
+    iteration, from the returned centroids, of the driver the fit ran, as
+    `template` (an estimator made as the fit's was) runs it on `fit_input`
+    (what the fit was given)."""
+    if a.model.meta.backend == "local":
+        return _next_centroids_local(template, fit_input, a)
     from repro.stream.lloyd import ooc_lloyd
 
     kw = {}
@@ -102,8 +115,21 @@ def next_centroids(template, store, a: FitAnswer) -> np.ndarray:
 
         kw = {"devices": shard_devices(template.mesh),
               "scheduler": template.scheduler}
-    res = ooc_lloyd(store, template.k, coeffs=a.model.params, iters=1,
+    res = ooc_lloyd(fit_input, template.k, coeffs=a.model.params, iters=1,
                     init=a.model.centroids, policy=template.policy, **kw)
+    return np.asarray(res.centroids)
+
+
+def _next_centroids_local(template, X, a: FitAnswer) -> np.ndarray:
+    """The local driver's update: one `core.lloyd.lloyd` iteration over the
+    program's own embedding of X under the fit's parameters and policy."""
+    from repro import embed
+    from repro.core.lloyd import lloyd
+
+    params = a.model.params
+    Y = embed.transform(params, jnp.asarray(X, jnp.float32), template.policy)
+    res = lloyd(Y, template.k, discrepancy=params.discrepancy, iters=1,
+                init=a.model.centroids, policy=template.policy)
     return np.asarray(res.centroids)
 
 
@@ -112,19 +138,20 @@ class FitSetup:
     config: dict
     traffic: dict
     seed: int
-    X: np.ndarray
-    store: object
+    rows: object  # the configuration's rows, in its row format
+    fmt: object  # the row format module (spec.rows_module)
+    fit_input: object  # what every fit of the window is given
     make_estimator: object  # () -> KernelKMeans
     devices: list
 
 
 def setup(cell, seed: int, policy=None) -> FitSetup:
-    from repro.stream.blockstore import BlockStore
-
     cfg, tr = cell.config, cell.traffic
-    X = data.make_rows(seed, cfg, cfg["n"])
-    store = BlockStore.from_array(X, cfg["block_rows"])
+    fmt = spec.rows_module(cfg.get("rows", "mixture"))
+    hold = tr.get("hold", "host")
+    rows = fmt.make(seed, cfg, cfg["n"], data.STREAM_FIT)
     devices = jax.devices()[: cell.chips]
+    fit_input = fmt.program_input(rows, cfg["block_rows"], hold, devices)
     mesh = None
     if tr["backend"] == "stream_shard":
         from jax.sharding import Mesh
@@ -135,11 +162,15 @@ def setup(cell, seed: int, policy=None) -> FitSetup:
         return estimator(cfg, tr["backend"], policy, mesh,
                          tr.get("scheduler", "lockstep"))
 
-    # warm every program on a small store with the same block shapes
-    n_warm = warm_rows(cfg["n"], cfg["block_rows"], len(devices))
-    warm_store = BlockStore.from_array(X[:n_warm], cfg["block_rows"])
-    make_estimator().fit(warm_store, key=fit_key(seed, WARM_KEY))
-    return FitSetup(cfg, tr, seed, X, store, make_estimator, devices)
+    if hold == "host":
+        # warm every program on a small store with the same block shapes
+        n_warm = warm_rows(cfg["n"], cfg["block_rows"], len(devices))
+        warm_input = fmt.program_input(fmt.head(rows, n_warm), cfg["block_rows"],
+                                       hold, devices)
+    else:
+        warm_input = fit_input
+    make_estimator().fit(warm_input, key=fit_key(seed, WARM_KEY))
+    return FitSetup(cfg, tr, seed, rows, fmt, fit_input, make_estimator, devices)
 
 
 @dataclasses.dataclass
@@ -172,7 +203,7 @@ def run_window(s: FitSetup, seconds: float, max_fits: int | None = None) -> FitW
     while time.perf_counter() < deadline:
         key_seed = FIT_KEY_BASE + i
         t0 = time.perf_counter()
-        est = s.make_estimator().fit(s.store, key=fit_key(s.seed, key_seed))
+        est = s.make_estimator().fit(s.fit_input, key=fit_key(s.seed, key_seed))
         t1 = time.perf_counter()
         i += 1
         if t1 > deadline:
@@ -180,7 +211,7 @@ def run_window(s: FitSetup, seconds: float, max_fits: int | None = None) -> FitW
             break
         t_last = t1
         rep = est.fit_report_
-        rows += int(rep.rows_seen) + s.store.n  # + phase 1's reservoir pass
+        rows += int(rep.rows_seen) + s.config["n"]  # + phase 1's reservoir pass
         phases.append(dict(rep.phases))
         fit_s.append(t1 - t0)
         answers.append(answer(est, len(answers), key_seed))
@@ -204,21 +235,23 @@ def compare(cell, s: FitSetup, window: FitWindow, max_fits: int,
     pick = rng.choice(len(answers), size=min(max_fits, len(answers)),
                       replace=False).tolist() if answers else []
     template = s.make_estimator()
-    records = [_record(cell, s.config, s.seed, s.X, answers[j], reference_control,
-                       lambda a: next_centroids(template, s.store, a))[0]
+    records = [_record(cell, s.config, s.seed, s.rows, s.fmt, answers[j],
+                       reference_control,
+                       lambda a: next_centroids(template, s.fit_input, a))[0]
                for j in sorted(pick)]
     return records, [r["numbers"] for r in records]
 
 
-def _record(cell, config: dict, seed: int, X: np.ndarray, a: FitAnswer,
+def _record(cell, config: dict, seed: int, rows, fmt, a: FitAnswer,
             reference_control: bool, step) -> tuple[dict, object]:
-    """(the numbers of one fit against the reference on its rows X, the
-    parameters the reference embedded with). `step(a)` gives the program's
-    next update of a fit that stopped at the cap."""
+    """(the numbers of one fit against the reference on its rows, held in
+    row format `fmt`, the parameters the reference embedded with). `step(a)`
+    gives the program's next update of a fit that stopped at the cap."""
     key = fit_key(seed, a.key_seed)
-    ref_params, checks = cell.reference.reference_params(config, key, a.params, X)
-    scored = score_rows(X, cell.reference.embed, ref_params, a.centroids,
-                        a.labels, config["discrepancy"])
+    ref_params, checks = cell.reference.reference_params(config, key, a.params, rows)
+    scored = score_rows(fmt.ref_chunks(rows), fmt.REF_CHUNK_ROWS,
+                        cell.reference.embed, ref_params, a.centroids, a.labels,
+                        config["discrepancy"])
     nums = dict(checks)
     nums.update(fit_numbers(a, scored, None if a.converged else step(a)))
     rec = {"fit": a.index, "n_iter": a.n_iter, "converged": a.converged,
@@ -226,7 +259,7 @@ def _record(cell, config: dict, seed: int, X: np.ndarray, a: FitAnswer,
     if reference_control:
         low = cell.reference.control_params(config, key, a.params)
         rec["reference_control"] = cell.reference.reference_params(
-            config, key, low, X)[1]
+            config, key, low, rows)[1]
     return rec, ref_params
 
 

@@ -26,7 +26,7 @@ from __future__ import annotations
 
 import dataclasses
 from functools import partial
-from typing import Any, Callable
+from typing import Any, Callable, Iterable
 
 import jax
 import jax.numpy as jnp
@@ -79,26 +79,27 @@ class FitAnswer:
         return self.n_iter < self.iters_cap
 
 
-def score_rows(X: np.ndarray, embed: Callable, ref_params: Any,
-               centroids: np.ndarray, labels: np.ndarray,
+def score_rows(chunks: Iterable[np.ndarray], chunk_rows: int, embed: Callable,
+               ref_params: Any, centroids: np.ndarray, labels: np.ndarray,
                discrepancy: str) -> dict:
-    """One pass over X in fixed chunks: nearest reference distances, the
-    returned labels' distances, and per-cluster sums of the reference
-    embedding over the returned labels (float64 on the host)."""
-    n = X.shape[0]
+    """One pass over the rows, given in order as dense float32 chunks of at
+    most `chunk_rows` rows (a row format's `ref_chunks`), each padded to
+    `chunk_rows`: nearest reference distances, the returned labels'
+    distances, and per-cluster sums of the reference embedding over the
+    returned labels (float64 on the host)."""
     k = centroids.shape[0]
     C = jnp.asarray(centroids, jnp.float32)
     sum_dmin = sum_dL = 0.0
     worst = 0.0
     Z = g = None
-    for lo in range(0, n, REF_CHUNK):
-        rows = min(REF_CHUNK, n - lo)
-        part = X[lo:lo + rows]
+    lo = 0
+    for part in chunks:
+        rows = part.shape[0]
         lab = labels[lo:lo + rows].astype(np.int32)
-        valid = np.ones(REF_CHUNK, np.float32)
-        if rows < REF_CHUNK:
-            part = np.pad(part, ((0, REF_CHUNK - rows), (0, 0)))
-            lab = np.pad(lab, (0, REF_CHUNK - rows))
+        valid = np.ones(chunk_rows, np.float32)
+        if rows < chunk_rows:
+            part = np.pad(part, ((0, chunk_rows - rows), (0, 0)))
+            lab = np.pad(lab, (0, chunk_rows - rows))
             valid[rows:] = 0.0
         dmin, dL, Zc, gc = _score_chunk(
             embed, discrepancy, ref_params, jnp.asarray(part), C,
@@ -113,9 +114,12 @@ def score_rows(X: np.ndarray, embed: Callable, ref_params: Any,
         gc = np.asarray(gc, np.float64)
         Z = Zc if Z is None else Z + Zc
         g = gc if g is None else g + gc
+        lo += rows
     assert Z is not None and g is not None and Z.shape[0] == k
+    if lo != labels.shape[0]:
+        raise ValueError(f"the chunks hold {lo} rows, the labels {labels.shape[0]}")
     return {"sum_dmin": sum_dmin, "sum_dL": sum_dL, "worst": worst, "Z": Z,
-            "g": g, "n": n}
+            "g": g, "n": lo}
 
 
 def fit_numbers(answer: FitAnswer, scored: dict,

@@ -12,13 +12,16 @@ reads the same work whatever precision or kernel carries it out:
   RFF step (h = half the embedding width, m = 2h):
       2bdh  the projection X W
       2bmk + bm as above.
+  Y-mode assign step, one pass over b already-embedded rows (the local
+  driver's Lloyd iteration):
+      2bmk + bm as above.
 
 Elementwise work (norms, exp, cos, sin, argmin) is not counted. The least
-bytes are what one call must read and write in HBM at float32: the block,
-the operands (landmarks and R, or W; the centroids), and Z, g, the labels and
-the cost. A share is the least time, max(operations / peak FLOP/s, bytes /
-peak bytes/s), over the measured kernel time; the FLOP/s peak is the chip's
-bf16 peak.
+bytes are what one call must read and write in HBM at float32: the block
+(X, or Y for the assign step), the operands (landmarks and R, or W; the
+centroids), and Z, g, the labels and the cost. A share is the least time,
+max(operations / peak FLOP/s, bytes / peak bytes/s), over the measured
+kernel time; the FLOP/s peak is the chip's bf16 peak.
 """
 from __future__ import annotations
 
@@ -50,6 +53,14 @@ def rff_step(b: float, d: int, h: int, k: int) -> tuple[float, float]:
     m = 2 * h
     ops = 2.0 * b * d * h + 2.0 * b * m * k + b * m
     nbytes = F32 * (b * d + d * h + k * m + k * m + k + b + 1)
+    return ops, nbytes
+
+
+def assign_step(b: float, m: int, k: int) -> tuple[float, float]:
+    """(operations, least bytes) of one Y-mode assign step over b embedded
+    rows of width m."""
+    ops = 2.0 * b * m * k + b * m
+    nbytes = F32 * (b * m + k * m + k * m + k + b + 1)
     return ops, nbytes
 
 

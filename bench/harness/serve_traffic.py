@@ -34,7 +34,7 @@ import time
 
 import numpy as np
 
-from . import data
+from . import data, rows_mixture
 from .fit_traffic import (FIT_KEY_BASE, _record, answer, estimator, fit_key,
                           next_centroids)
 from .spec import Outcome
@@ -195,7 +195,7 @@ def compare(cell, s: ServeSetup, w: ServeWindow,
     from .reference import nearest_table
 
     model, ref_params = _record(
-        cell, s.config, s.seed, s.X_fit, s.fitted, reference_control,
+        cell, s.config, s.seed, s.X_fit, rows_mixture, s.fitted, reference_control,
         lambda a: next_centroids(s.estimator, s.fit_store, a))
     D = nearest_table(s.X_held, cell.reference.embed, ref_params,
                       s.fitted.centroids, s.config["discrepancy"])

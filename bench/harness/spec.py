@@ -4,8 +4,10 @@ Nothing here knows a cell, a configuration, a traffic mix or a metric by
 name. A configuration `c` is `configs/c.json` (its sizes) with its plain
 reference `configs/c.py` beside it; a traffic mix `t` is `traffic/t.json`;
 a per-layer metric `p` is read by `metrics/p.py`. A traffic file's `kind`
-names the generator that reads it, `harness/<kind>_traffic.py`. Adding a cell
-means adding those files and the entries in BENCHMARK.json.
+names the generator that reads it, `harness/<kind>_traffic.py`; a
+configuration's `rows` (default "mixture") names the row format that makes
+and holds its rows, `harness/rows_<rows>.py`. Adding a cell means adding
+those files and the entries in BENCHMARK.json.
 """
 from __future__ import annotations
 
@@ -65,6 +67,14 @@ def traffic_module(kind: str) -> ModuleType:
     if not re.fullmatch(r"[a-z][a-z0-9_]*", kind):
         raise ValueError(f"bad traffic kind {kind!r}")
     return importlib.import_module(f"{__package__}.{kind}_traffic")
+
+
+def rows_module(fmt: str) -> ModuleType:
+    """The row format `fmt`: `harness/rows_<fmt>.py` (see rows_mixture.py
+    for what one provides)."""
+    if not re.fullmatch(r"[a-z][a-z0-9_]*", fmt):
+        raise ValueError(f"bad row format {fmt!r}")
+    return importlib.import_module(f"{__package__}.rows_{fmt}")
 
 
 def _reports(metric: dict, cell: str) -> bool:

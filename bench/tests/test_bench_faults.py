@@ -19,8 +19,9 @@ from bench.tests.cells import small_cell
 
 
 def run(name, seed=21, seconds=600.0, **kw):
-    kw.setdefault("window_fits", 1 if name.endswith(".fit") else None)
-    return runner.run_cell(small_cell(name), seed, seconds, False,
+    cell = small_cell(name)
+    kw.setdefault("window_fits", 1 if cell.traffic["kind"] == "fit" else None)
+    return runner.run_cell(cell, seed, seconds, False,
                            t_process=time.perf_counter(), require_tpu=False,
                            compile_cache=False, **kw)
 
@@ -29,7 +30,8 @@ def failed(r):
     return {k for k, c in r["checks"].items() if c["value"] > c["limit"]}
 
 
-@pytest.mark.parametrize("name", ["covtype-rff.fit", "imagenet-nystrom.fit"])
+@pytest.mark.parametrize("name", ["covtype-rff.fit", "imagenet-nystrom.fit",
+                                  "imagenet-nystrom.fit-resident"])
 @pytest.mark.parametrize("fault", sorted(faults.FIT))
 def test_fit_fault_is_caught(monkeypatch, name, fault):
     faults.FIT[fault](monkeypatch)
