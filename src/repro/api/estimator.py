@@ -36,7 +36,7 @@ from repro.core.kernels_fn import Kernel, self_tuned_rbf
 from repro.core.lloyd import block_cost, centroid_update, kmeanspp_init
 from repro.kernels import ops
 from repro.policy import ComputePolicy
-from repro.stream.blockstore import BlockStore
+from repro.stream.blockstore import BlockStore, CSRBlock, is_csr
 from repro.stream.reservoir import reservoir_sample
 
 Array = jax.Array
@@ -44,6 +44,13 @@ Array = jax.Array
 # backend="auto": in-memory arrays at or beyond this many rows are clustered
 # out-of-core (wrapped in a BlockStore) instead of fully embedded on device.
 AUTO_STREAM_ROWS = 2_000_000
+
+
+def _reject_csr(X, op: str) -> None:
+    if is_csr(X):
+        raise NotImplementedError(
+            f"{op} of CSR rows is not supported: CSR input is fitted by "
+            "KernelKMeans(method='nystrom', backend='stream').fit only")
 
 
 def phase1_keys(key: Array) -> tuple[Array, Array, Array]:
@@ -87,7 +94,9 @@ class KernelKMeans:
                      BlockStore input plus a mesh with >1 data-axis device,
                      "stream" for any other BlockStore input, "shard_map" when
                      a mesh was given, "stream" for arrays with >=
-                     AUTO_STREAM_ROWS rows, else "local".
+                     AUTO_STREAM_ROWS rows, else "local". CSR input (a
+                     scipy.sparse matrix or a CSR BlockStore) is fitted by
+                     "stream" alone, with method "nystrom"; auto picks it.
     l, m, t, q:      landmark count, embedding dim per block, SD subset size,
                      ensemble blocks — as in the paper. Landmark-free members
                      (rff, tensorsketch) read only m.
@@ -175,6 +184,8 @@ class KernelKMeans:
     def _choose_backend(self, X) -> str:
         if self.backend != "auto":
             return self.backend
+        if is_csr(X):
+            return "stream"  # the one backend with a sparse block route
         if isinstance(X, BlockStore):
             # Blocked input + a mesh with >1 data-axis device -> shard the
             # stream across the mesh (one producer + one block shard per
@@ -198,6 +209,15 @@ class KernelKMeans:
         if not isinstance(self.kernel, Kernel) and self.kernel == "rbf" \
                 and not self.kernel_params:
             # paper Section 9 self-tuning, estimated on the landmark sample
+            if isinstance(sample, CSRBlock):
+                # the <= 512 rows self_tuned_rbf draws, densified; sigma is a
+                # mean over all their pairs, so their order does not matter
+                idx = jax.random.choice(
+                    jax.random.PRNGKey(self.random_state), sample.shape[0],
+                    (min(512, sample.shape[0]),), replace=False)
+                rows = jnp.asarray(sample.toarray(np.asarray(idx)))
+                return self_tuned_rbf(rows, sample=rows.shape[0],
+                                      seed=self.random_state)
             return self_tuned_rbf(jnp.asarray(sample), seed=self.random_state)
         return resolve_kernel(self.kernel, self.kernel_params)
 
@@ -221,7 +241,11 @@ class KernelKMeans:
         """The backend-independent front of every fit/sweep: blocked view,
         landmark sample, embedding fit, seeding pool. Returns
         (store, array, params, pool, k_seed) — k-means++ draws come off
-        `k_seed` per restart, identically for fit() and sweep()."""
+        `k_seed` per restart, identically for fit() and sweep(). CSR input
+        is wrapped as a CSR store and sampled as CSR rows: nothing of it is
+        densified but the landmarks."""
+        if is_csr(X) and not isinstance(X, BlockStore):
+            X = BlockStore.from_csr(X, self.block_rows)
         if isinstance(X, BlockStore):
             self._reject_sharded(X, "fit")
             store, array = X, None
@@ -242,10 +266,10 @@ class KernelKMeans:
         k_sample, k_fit, k_seed = phase1_keys(key)
         self._phases = {}
         with self._phase("reservoir"):
-            sample = jnp.asarray(
-                reservoir_sample(store, self.landmark_sample,
-                                 seed=int(k_sample[-1]))
-            )
+            sample = reservoir_sample(store, self.landmark_sample,
+                                      seed=int(k_sample[-1]))
+            if not isinstance(sample, CSRBlock):
+                sample = jnp.asarray(sample)
         with self._phase("embed_fit"):
             params, pool = self._fit_params_and_pool(sample, k_fit)
             jax.block_until_ready(pool)
@@ -303,8 +327,14 @@ class KernelKMeans:
         completed Lloyd iteration is repeated; pair with `sweep`'s staged
         embedding or a Y-block store to also skip re-embedding).
 
+        CSR rows (a scipy.sparse matrix or a CSR ``BlockStore``) are fitted
+        without densifying them, by ``method="nystrom"`` on the ``stream``
+        backend (``auto`` picks it); any other method or backend raises
+        ``NotImplementedError``.
+
         Args:
-            X: (n, d) array-like, or a ``BlockStore`` for out-of-core input.
+            X: (n, d) array-like, a ``BlockStore`` for out-of-core input, or
+                CSR rows.
             y: Ignored (sklearn signature compatibility).
             key: PRNG key; ``None`` seeds from ``random_state``.
             checkpoint_dir: Root directory for mid-fit Lloyd checkpoints
@@ -317,6 +347,10 @@ class KernelKMeans:
         name = self._choose_backend(X)
         backend = get_backend(name)  # fail fast, before the embedding fit
         get_embedding(self.method)  # likewise: reject typos before streaming data
+        if is_csr(X) and (name != "stream" or self.method != "nystrom"):
+            raise NotImplementedError(
+                f"CSR rows are fitted by method='nystrom' on backend='stream' "
+                f"only, not method={self.method!r} on backend={name!r}")
         metrics_before = obs.snapshot("engine.")
         ctx = self._prepare(X, key, name, checkpoint_dir)
         with self._phase("lloyd"):
@@ -384,6 +418,7 @@ class KernelKMeans:
         """
         from repro.sweep import sweep_estimator
 
+        _reject_csr(X, "sweep")
         return sweep_estimator(
             self, X, k_grid, restarts=restarts, key=key,
             checkpoint_dir=checkpoint_dir,
@@ -404,6 +439,7 @@ class KernelKMeans:
         Returns:
             self, updated in place.
         """
+        _reject_csr(X, "partial_fit")
         Xb = jnp.asarray(np.asarray(X, np.float32))
         if self.model_ is None:
             # landmark-free members (rff, tensorsketch) only read the input
@@ -549,6 +585,7 @@ class KernelKMeans:
             (n,) int32 cluster labels.
         """
         model = self._require_model()
+        _reject_csr(X, "predict")
         if isinstance(X, BlockStore):
             from repro.stream.engine import map_reduce
 
@@ -583,6 +620,7 @@ class KernelKMeans:
             The embedded rows, in the input's container shape.
         """
         model = self._require_model()
+        _reject_csr(X, "transform")
         if isinstance(X, BlockStore):
             from repro.stream.lloyd import stream_embed
 
@@ -603,6 +641,7 @@ class KernelKMeans:
             ``-sum_i e(y_i, c_label(i))`` as a float.
         """
         model = self._require_model()
+        _reject_csr(X, "score")
         disc = model.discrepancy
         if isinstance(X, BlockStore):
             from repro.stream.engine import map_reduce

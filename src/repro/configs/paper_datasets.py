@@ -25,6 +25,9 @@ PAPER_DATASETS = {
     "usps": PaperDataset("usps", 9_298, 256, 10, "tanh", (0.0045, 0.11)),
     "pie": PaperDataset("pie", 11_554, 4_096, 68, "rbf", (), bench_n=11_554, separation=2.0),
     "mnist": PaperDataset("mnist", 70_000, 784, 10, "poly", (5, 1.0), bench_n=20_000),
+    # A 20,000-row dense CPU stand-in. The chip configuration is
+    # bench/configs/rcv1-nystrom.json: all 193,844 rows held as CSR (~75
+    # nonzeros a row) and fitted through the sparse block route.
     "rcv1": PaperDataset("rcv1", 193_844, 47_236, 103, "rbf", (), bench_n=20_000, separation=2.0),
     "covtype": PaperDataset("covtype", 581_012, 54, 7, "rbf", (), bench_n=50_000, separation=1.5),
     "imagenet": PaperDataset("imagenet", 1_262_102, 900, 164, "rbf", (), bench_n=50_000, separation=1.5),

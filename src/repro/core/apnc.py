@@ -82,6 +82,43 @@ def embed(X: Array, coeffs: APNCCoefficients) -> Array:
     return jnp.concatenate(parts, axis=-1)
 
 
+def csr_operands(landmarks: Array) -> tuple[Array, Array]:
+    """What the sparse embedding reads of the landmarks (q, l_b, d): their
+    rows transposed into one (d, q * l_b) table, gathered by column id, and
+    their squared norms (1, q * l_b)."""
+    L = landmarks.reshape(-1, landmarks.shape[-1])
+    return L.T, jnp.sum(L * L, axis=-1)[None, :]
+
+
+def embed_csr(values: Array, cols: Array, indptr: Array, landmarks_t: Array,
+              landmarks_sq: Array, R: Array, kernel: Kernel) -> Array:
+    """APNC embedding of one CSR block (`stream.blockstore.CSRBlock` leaves)
+    without densifying it: (b, q * m_b).
+
+    The gram's cross term X L^T is summed per row from the nonzeros against
+    the gathered rows of L^T (`csr_operands`), ||x||^2 from the values; the
+    kernel then comes from its parts (`Kernel.from_cross`) and is contracted
+    with each R^(b) at HIGHEST, as `embed` does. Padding slots carry value 0
+    and add nothing to either sum.
+    """
+    b = indptr.shape[0] - 1
+    # Row of each slot: the row starts at or before it. Padding slots lie
+    # after the last row's nonzeros and land in the last row, with value 0.
+    row = jnp.cumsum(
+        jnp.zeros(values.shape[0], jnp.int32).at[indptr[1:-1]].add(1, mode="drop"))
+    cross = jax.ops.segment_sum(values[:, None] * landmarks_t[cols], row,
+                                num_segments=b, indices_are_sorted=True)
+    xx = jax.ops.segment_sum(values * values, row, num_segments=b,
+                             indices_are_sorted=True)[:, None]
+    K = kernel.from_cross(cross, xx, landmarks_sq)
+    l_b = R.shape[-1]
+    parts = [
+        jnp.dot(K[:, i * l_b:(i + 1) * l_b], R[i].T, precision=MATMUL_PRECISION)
+        for i in range(R.shape[0])
+    ]
+    return jnp.concatenate(parts, axis=-1)
+
+
 def pairwise_discrepancy(Y: Array, C: Array, discrepancy: Discrepancy) -> Array:
     """e(y_i, c_j) for all pairs: Y (n, m), C (k, m) -> (n, k).
 

@@ -48,7 +48,16 @@ class Kernel:
         """Dense kernel matrix K[i, j] = kappa(X[i], Z[j]); shape (n, l)."""
         if self.name == "rbf":
             return jnp.exp(-self.gamma * _sq_dists(X, Z))
-        dot = jnp.dot(X, Z.T, precision=MATMUL_PRECISION)
+        return self._from_dot(jnp.dot(X, Z.T, precision=MATMUL_PRECISION))
+
+    def from_cross(self, cross: Array, xx: Array, zz: Array) -> Array:
+        """The gram from its parts, for rows that are never densified:
+        cross = X Z^T (n, l), xx = ||x||^2 (n, 1), zz = ||z||^2 (1, l)."""
+        if self.name == "rbf":
+            return jnp.exp(-self.gamma * jnp.maximum(xx - 2.0 * cross + zz, 0.0))
+        return self._from_dot(cross)
+
+    def _from_dot(self, dot: Array) -> Array:
         if self.name == "poly":
             return (dot + self.coef0) ** self.degree
         if self.name == "tanh":

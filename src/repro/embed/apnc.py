@@ -17,11 +17,13 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.apnc import APNCCoefficients, embed
 from repro.core.kernels_fn import Kernel
 from repro.embed.base import Embedding, EmbeddingProps, register_embedding
 from repro.policy import MATMUL_PRECISION
+from repro.stream.blockstore import CSRBlock
 
 Array = jax.Array
 
@@ -45,9 +47,13 @@ def _inv_sqrt_clamped(lam: Array) -> Array:
 def sample_landmarks(key: Array, X: Array, l: int) -> Array:
     """Algorithm 3 map phase: uniform sample of l rows (deterministic under key —
     the Bernoulli(l/n) of the paper is replaced by sampling without replacement so
-    restarts reproduce exactly; the distribution is the same conditional on size)."""
+    restarts reproduce exactly; the distribution is the same conditional on size).
+    A CSR sample (`CSRBlock`) densifies only the l rows drawn: the same rows
+    its dense twin gives under the same key."""
     n = X.shape[0]
     idx = jax.random.choice(key, n, (l,), replace=False)
+    if isinstance(X, CSRBlock):
+        return jnp.asarray(X.toarray(np.asarray(idx)))
     return X[idx]
 
 

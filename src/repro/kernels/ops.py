@@ -12,7 +12,7 @@ from functools import partial, wraps
 import jax
 import jax.numpy as jnp
 
-from repro.core.apnc import APNCCoefficients
+from repro.core.apnc import APNCCoefficients, csr_operands, embed_csr
 from repro.core.kernels_fn import Kernel
 from repro.embed.rff import RFFParams
 from repro.kernels import apnc_assign as _assign
@@ -20,7 +20,7 @@ from repro.kernels import apnc_embed as _embed
 from repro.kernels import lloyd_step as _lloyd_step
 from repro.kernels import rff_embed as _rff
 from repro.policy import ComputePolicy, resolve_policy
-from repro.stream.blockstore import EncodedBlock
+from repro.stream.blockstore import CSRBlock, EncodedBlock
 
 Array = jax.Array
 
@@ -147,6 +147,44 @@ def rff_embed(
     )
 
 
+# ---------------------------------------------------------------------------
+# Sparse rows: the APNC embedding of a CSR block
+# ---------------------------------------------------------------------------
+
+
+_csr_operands = jax.jit(csr_operands)
+
+
+@partial(jax.jit, static_argnames=("kernel",))
+def _sparse_embed_block(values, cols, indptr, landmarks_t, landmarks_sq, R, kernel):
+    """One CSR block's APNC embedding (core.apnc.embed_csr): an XLA gather of
+    L^T rows by column id and a sorted segment sum per row, then the rbf (or
+    other) kernel and the contraction with R^T at HIGHEST. A dispatch of its
+    own, so a device trace finds its ops under this name."""
+    return embed_csr(values, cols, indptr, landmarks_t, landmarks_sq, R, kernel)
+
+
+def sparse_embed(blk: CSRBlock, params, operands=None,
+                 policy: ComputePolicy | None = None) -> Array:
+    """APNC embedding (Nystrom or SD coefficients) of a `CSRBlock` (host or
+    device). `operands` are `csr_operands(params.landmarks)`, when the
+    caller keeps them. A policy asking for bf16 off the Pallas path computes
+    in bf16 (f32 out), as `embed.transform` does for dense rows."""
+    if not isinstance(params, APNCCoefficients):
+        raise NotImplementedError(
+            f"CSR rows embed only through APNC coefficients (nystrom, sd), "
+            f"not {type(params).__name__}")
+    lt, lsq = operands if operands is not None else _csr_operands(params.landmarks)
+    vals, R = blk.values, params.R
+    pol = resolve_policy(policy, owner="ops.sparse_embed: ")
+    if pol.precision == "bf16" and not pol.resolve_pallas():
+        vals, lt, lsq, R = (jnp.asarray(a, jnp.bfloat16) for a in (vals, lt, lsq, R))
+        return _sparse_embed_block(vals, blk.cols, blk.indptr, lt, lsq, R,
+                                   params.kernel).astype(jnp.float32)
+    return _sparse_embed_block(vals, blk.cols, blk.indptr, lt, lsq, R,
+                               params.kernel)
+
+
 @partial(jax.jit, static_argnames=("policy",))
 def _embed_block_map(x: Array, params, policy: ComputePolicy) -> Array:
     from repro import embed  # single routing point for EVERY registered member
@@ -162,8 +200,11 @@ def embed_block_map(
     per (block_rows, d) block for ANY registered embedding's params, routed
     per ComputePolicy (use_pallas= is a deprecated alias). The jit
     specializes per params pytree type, so the dispatch on the member's
-    transform happens at trace time, not per block."""
+    transform happens at trace time, not per block. A `CSRBlock` takes
+    `sparse_embed`."""
     pol = resolve_policy(policy, use_pallas, owner="ops.embed_block_map: ")
+    if isinstance(x, CSRBlock):
+        return sparse_embed(x, params, policy=pol)
     return _embed_block_map(x, params, pol)
 
 
@@ -427,10 +468,17 @@ class LloydStepPlan:
       * otherwise: the jnp reference chain — bit-identical to the
         pre-plan drivers (it IS the same jitted functions).
 
+    A `CSRBlock` (sparse rows, X-mode with APNC params) is told apart by a
+    Python isinstance before any of these and takes its own route whatever
+    the policy: `_sparse_embed_block` gives Y, which feeds the Y-mode assign
+    (`_assign_padded` under a Pallas policy). Dense blocks never see it.
+
     Both methods are pure and traceable (safe inside lax.while_loop / vmap);
     `block_map(cell)` / `assign_map(cell)` wrap them for the stream engine —
     host-level closures over a 1-element centroids cell, instrumented with the
-    `lloyd.fused_step` span and `engine.fused_dispatches` counter when fused.
+    `lloyd.fused_step` span and `engine.fused_dispatches` counter when fused,
+    and, for a CSR block, the `lloyd.sparse_step` span and the
+    `sparse.blocks`, `sparse.nnz` and `sparse.slots` counters.
     """
 
     def __init__(self, *, params, discrepancy: str, policy: ComputePolicy, member):
@@ -438,6 +486,15 @@ class LloydStepPlan:
         self.discrepancy = discrepancy
         self.policy = policy
         self.fused_member = member
+        self._csr_ops = None  # csr_operands of the landmarks, made once
+
+    def _sparse_y(self, block: CSRBlock) -> Array:
+        """Y of a CSR block. The transposed landmark table is built once a
+        plan (kept only when the params are concrete, not traced)."""
+        if self._csr_ops is None and isinstance(self.params, APNCCoefficients) \
+                and not isinstance(self.params.landmarks, jax.core.Tracer):
+            self._csr_ops = _csr_operands(self.params.landmarks)
+        return sparse_embed(block, self.params, self._csr_ops, self.policy)
 
     @property
     def fused(self) -> bool:
@@ -447,7 +504,11 @@ class LloydStepPlan:
         """(Z, g, labels, cost) for one block under `centroids`. Y-mode also
         accepts a quantized `EncodedBlock` (the compressed staged cache's wire
         form): the payload + scale dequantize on device — in VMEM inside the
-        fused dequant kernel under a Pallas policy (DESIGN.md §17)."""
+        fused dequant kernel under a Pallas policy (DESIGN.md §17). X-mode
+        also accepts a `CSRBlock`: the sparse embed, then the Y-mode step."""
+        if isinstance(block, CSRBlock):
+            return _assign_stats_cost_y(self._sparse_y(block), centroids,
+                                        self.discrepancy, self.policy)
         if self.params is None:
             if isinstance(block, EncodedBlock):
                 return _dequant_assign_stats_cost(
@@ -461,7 +522,10 @@ class LloydStepPlan:
 
     def assign(self, block: Array, centroids: Array):
         """(labels, cost) for one block — the final / scoring pass. Y-mode
-        accepts `EncodedBlock` like `step`."""
+        accepts `EncodedBlock` like `step`, X-mode a `CSRBlock`."""
+        if isinstance(block, CSRBlock):
+            return _assign_cost_y(self._sparse_y(block), centroids,
+                                  self.discrepancy, self.policy)
         if self.params is None:
             if isinstance(block, EncodedBlock):
                 return _dequant_assign_cost(
@@ -475,13 +539,27 @@ class LloydStepPlan:
         return _embed_assign_cost_x(block, self.params, centroids, self.policy)
 
     def _instrumented(self, fn):
-        if not self.fused:
+        sparse = isinstance(self.params, APNCCoefficients)
+        if not (self.fused or sparse):
             return fn
         from repro import obs
 
         fused_dispatches = obs.counter("engine.fused_dispatches")
+        csr_blocks = obs.counter("sparse.blocks")
+        csr_nnz = obs.counter("sparse.nnz")
+        csr_slots = obs.counter("sparse.slots")
 
         def wrapped(block):
+            if isinstance(block, CSRBlock):
+                with obs.span("lloyd.sparse_step", cat="lloyd",
+                              rows=block.shape[0], nnz=block.nnz):
+                    out = fn(block)
+                csr_blocks.inc()
+                csr_nnz.inc(block.nnz)
+                csr_slots.inc(block.slots)
+                return out
+            if not self.fused:
+                return fn(block)
             with obs.span("lloyd.fused_step", cat="lloyd", member=self.fused_member):
                 out = fn(block)
             fused_dispatches.inc()

@@ -42,6 +42,7 @@ import numpy as np
 from repro import obs
 from repro.serving.admission import AdmissionController, Shed
 from repro.serving.registry import ModelRegistry, ServingModel
+from repro.stream.blockstore import is_csr
 from repro.stream.microbatch import MicroBatcher
 
 
@@ -163,6 +164,8 @@ class ServingTier:
         if not self._running:
             raise RuntimeError("serving tier is not running (call start())")
         self.registry.resolve(model)  # unknown names fail fast, not in-batch
+        if is_csr(x):
+            raise NotImplementedError("serving CSR rows is not supported")
         self.admission.admit()  # raises Shed at the in-flight bound
         req = ServeRequest(
             request_id, np.asarray(x, np.float32), model, self.clock()

@@ -19,13 +19,26 @@ keeps seeing f32 — while codec-aware consumers (the stream engine's producer,
 the fused Lloyd plan) move the quantized `EncodedBlock` wire form to the
 device instead and dequantize in VMEM. Both read paths share the global-id
 `_read*` seam, so the unwritten-block guard protects them equally.
+
+Sparse rows (DESIGN.md §18) are held as CSR: `BlockStore.from_csr` cuts a CSR
+matrix into row blocks, each a `CSRBlock` of values, column ids and row
+pointers with its nonzeros padded to ONE capacity for the whole store, so
+every full block has one compiled shape. No dense block of the input is ever
+built unless `materialize()` is asked for.
 """
 from __future__ import annotations
 
 from pathlib import Path
 from typing import Callable, Iterator, NamedTuple
 
+import jax
 import numpy as np
+
+LANE = 128  # TPU lane width
+# A CSR store's nonzero capacity is a multiple of SLOT_TILE slots (32 lanes):
+# on a TPU v5e, fits whose capacity had a small power-of-two factor (128 or
+# 256 times an odd number) ran 8-13% slower than one at 77 x 4,096 (PERF.md).
+SLOT_TILE = 32 * LANE
 
 
 class EncodedBlock(NamedTuple):
@@ -51,6 +64,106 @@ class BlockHeader(NamedTuple):
     rows: int  # row count of this block (ragged final block < block_rows)
     d: int  # feature width
     scale: float  # dequant factor (1.0 for f32/bf16)
+
+
+@jax.tree_util.register_pytree_node_class
+class CSRBlock:
+    """One block of sparse rows in CSR form.
+
+    `values` (f32) and `cols` (int32) hold the block's nonzeros row by row,
+    padded to `slots` (the store's capacity); `indptr` (rows + 1, int32)
+    points into them. Padding slots sit after the last row's nonzeros and
+    carry value 0 at column 0, so any sum over slots is unchanged by them.
+    `d` and `nnz` (the real nonzeros) ride as static pytree data: the three
+    arrays are the leaves, so `jax.device_put` moves the block whole and a
+    jit over the leaves compiles once per (rows, slots).
+    """
+
+    __slots__ = ("values", "cols", "indptr", "d", "nnz")
+
+    def __init__(self, values, cols, indptr, *, d: int, nnz: int):
+        self.values, self.cols, self.indptr = values, cols, indptr
+        self.d, self.nnz = int(d), int(nnz)
+
+    def tree_flatten(self):
+        return (self.values, self.cols, self.indptr), (self.d, self.nnz)
+
+    @classmethod
+    def tree_unflatten(cls, aux, leaves):
+        return cls(*leaves, d=aux[0], nnz=aux[1])
+
+    @classmethod
+    def from_rows(cls, indptr, cols, values, *, d: int,
+                  capacity: int | None = None) -> "CSRBlock":
+        """Host block from CSR arrays (`indptr` may start anywhere: it is
+        rebased to 0). `capacity=None` pads to `bucket(nnz)`."""
+        indptr = np.asarray(indptr, np.int64)
+        lo, hi = int(indptr[0]), int(indptr[-1])
+        nnz = hi - lo
+        cap = bucket(nnz) if capacity is None else int(capacity)
+        if cap < nnz:
+            raise ValueError(f"capacity {cap} < the block's {nnz} nonzeros")
+        v = np.zeros(cap, np.float32)
+        c = np.zeros(cap, np.int32)
+        v[:nnz] = np.asarray(values[lo:hi], np.float32)
+        c[:nnz] = np.asarray(cols[lo:hi], np.int32)
+        return cls(v, c, (indptr - lo).astype(np.int32), d=d, nnz=nnz)
+
+    @property
+    def shape(self) -> tuple[int, int]:
+        return (self.indptr.shape[0] - 1, self.d)
+
+    @property
+    def slots(self) -> int:
+        return self.values.shape[0]
+
+    @property
+    def nbytes(self) -> int:
+        return sum(int(a.nbytes) for a in (self.values, self.cols, self.indptr))
+
+    def __getitem__(self, rows: slice) -> "CSRBlock":
+        """The first rows of a host block, `block[:h]` (the seeding pool of
+        a sample): the only slice a CSR block takes. The result is padded
+        to its own `bucket`."""
+        if not isinstance(rows, slice) or rows.start not in (None, 0) \
+                or rows.step not in (None, 1):
+            raise TypeError("a CSRBlock takes only a leading row slice [:h]")
+        h = min(self.shape[0], self.shape[0] if rows.stop is None else rows.stop)
+        return CSRBlock.from_rows(np.asarray(self.indptr)[:h + 1],
+                                  np.asarray(self.cols), np.asarray(self.values),
+                                  d=self.d)
+
+    def row(self, r: int) -> tuple[np.ndarray, np.ndarray]:
+        """(column ids, values) of row r of a host block."""
+        lo, hi = int(self.indptr[r]), int(self.indptr[r + 1])
+        return self.cols[lo:hi], self.values[lo:hi]
+
+    def toarray(self, rows=None) -> np.ndarray:
+        """Dense (rows, d) float32 on the host: every row, or the rows
+        indexed by `rows`. Duplicate column ids add, as in any CSR."""
+        indptr = np.asarray(self.indptr)
+        cols, vals = np.asarray(self.cols), np.asarray(self.values)
+        rows = np.arange(self.shape[0]) if rows is None else np.asarray(rows)
+        out = np.zeros((rows.shape[0], self.d), np.float32)
+        lens = indptr[rows + 1] - indptr[rows]
+        at = np.repeat(indptr[rows], lens) + (
+            np.arange(int(lens.sum())) - np.repeat(np.cumsum(lens) - lens, lens))
+        np.add.at(out, (np.repeat(np.arange(rows.shape[0]), lens), cols[at]),
+                  vals[at])
+        return out
+
+
+def bucket(nnz: int) -> int:
+    """Slot capacity of a CSR row set built on its own (a reservoir sample,
+    a seeding pool): the next power of two, at least one lane, so row sets
+    of similar size share one compiled shape."""
+    return max(LANE, 1 << max(int(nnz) - 1, 0).bit_length())
+
+
+def is_csr(x) -> bool:
+    """A CSR input: a `CSRBlockStore`, or a scipy.sparse matrix or array
+    (any format; converted with `.tocsr()`)."""
+    return isinstance(x, CSRBlockStore) or callable(getattr(x, "tocsr", None))
 
 
 def _bf16_dtype() -> np.dtype:
@@ -284,6 +397,20 @@ class BlockStore:
         )
 
     @classmethod
+    def from_csr(cls, X, block_rows: int, *,
+                 capacity: int | None = None) -> "CSRBlockStore":
+        """View a CSR matrix (a scipy.sparse matrix or array, or anything
+        with `indptr`, `indices`, `data` and `shape`) as blocks of
+        `CSRBlock`s; nothing is densified. `capacity` gives the blocks more
+        slots than the store's own rule (`CSRBlockStore`), so that another
+        store shares their compiled shape."""
+        if callable(getattr(X, "tocsr", None)):
+            X = X.tocsr()
+        n, d = X.shape
+        return CSRBlockStore(X.indptr, X.indices, X.data, n=n, d=d,
+                             block_rows=block_rows, capacity=capacity)
+
+    @classmethod
     def from_generator(
         cls, make_block: Callable[[int], np.ndarray], *,
         n: int, d: int, block_rows: int, dtype=np.float32,
@@ -437,3 +564,66 @@ class WritableBlockStore(BlockStore):
         """Total bytes of the staging buffer (+ the scale rows for int8)."""
         extra = self._scales.nbytes if self.codec == "int8" else 0
         return self._buf.nbytes + extra
+
+
+class CSRBlockStore(BlockStore):
+    """Row blocks of a host CSR matrix; `get(i)` is a `CSRBlock`.
+
+    Capacity rule: every block's nonzeros are padded to `nnz_capacity`, the
+    store's largest block rounded up to a multiple of `SLOT_TILE`, so every
+    full block (and the short last one, by its rows) has one compiled shape.
+    A caller may ask for more (`capacity=`, at least the largest block) to
+    share a shape with another store. `materialize()` densifies, and only it does.
+    """
+
+    def __init__(self, indptr, indices, data, *, n: int, d: int,
+                 block_rows: int, capacity: int | None = None,
+                 block_ids: tuple[int, ...] | None = None):
+        self._indptr = np.asarray(indptr, np.int64)
+        self._indices = np.asarray(indices, np.int32)
+        self._data = np.asarray(data, np.float32)
+        if self._indptr.shape != (n + 1,):
+            raise ValueError(f"indptr has shape {self._indptr.shape}, want ({n + 1},)")
+        super().__init__(self._block, n=n, d=d, block_rows=block_rows,
+                         block_ids=block_ids)
+        edges = self._indptr[np.minimum(
+            np.arange(0, -(-n // self.block_rows) + 1) * self.block_rows, n)]
+        largest = int(np.max(np.diff(edges), initial=0))
+        rule = max(SLOT_TILE, -(-largest // SLOT_TILE) * SLOT_TILE)
+        if capacity is not None and capacity < largest:
+            raise ValueError(
+                f"capacity {capacity} is below the largest block's {largest} "
+                "nonzeros")
+        self.nnz_capacity = rule if capacity is None else int(capacity)
+
+    @property
+    def nnz(self) -> int:
+        """Real nonzeros of the whole (unsharded) matrix."""
+        return int(self._indptr[-1] - self._indptr[0])
+
+    def _block(self, gid: int) -> CSRBlock:
+        lo = gid * self.block_rows
+        hi = min(lo + self.block_rows, self.n)
+        return CSRBlock.from_rows(self._indptr[lo:hi + 1], self._indices,
+                                  self._data, d=self.d,
+                                  capacity=self.nnz_capacity)
+
+    def _read(self, gid: int) -> CSRBlock:
+        return self._block(gid)
+
+    def shard(self, index: int, num_shards: int) -> "CSRBlockStore":
+        if not 0 <= index < num_shards:
+            raise ValueError(f"shard index {index} out of range for {num_shards}")
+        return CSRBlockStore(
+            self._indptr, self._indices, self._data, n=self.n, d=self.d,
+            block_rows=self.block_rows, capacity=self.nnz_capacity,
+            block_ids=self._block_ids[index::num_shards])
+
+    def map_rows(self, fn, d_out):
+        raise NotImplementedError("map_rows over CSR blocks is not supported")
+
+    def materialize(self) -> np.ndarray:
+        """Every block densified and concatenated: (rows, d) float32. Tests
+        and small data only; an RCV1-sized store is 36.6 GB dense."""
+        return np.concatenate([self.get(i).toarray()
+                               for i in range(self.num_blocks)], axis=0)

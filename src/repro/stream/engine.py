@@ -23,6 +23,11 @@ consumer thread keeps queuing blocks on the device instead of waiting out a
 round trip per block. At most L outputs are pending per consumer, which keeps
 device memory O(block) and bounds how far the host runs ahead of the device.
 
+A block is any pytree of host arrays: a dense block, the wire form of a
+compressed cache (`EncodedBlock`) or sparse rows (`CSRBlock`). The producer's
+`device_put` moves it leaf by leaf, and the deferred host copy reads only the
+leaves `emit` picks from the map's output.
+
 Device placement: `device=` commits every produced block to one specific
 device instead of the default. This is the per-device-queue building block of
 the sharded executor (`repro.stream.sharded`): each device of a mesh gets its
@@ -40,7 +45,7 @@ from typing import Any, Callable
 import jax
 
 from repro import obs
-from repro.stream.blockstore import BlockStore, EncodedBlock, WritableBlockStore
+from repro.stream.blockstore import BlockStore, WritableBlockStore
 
 _STOP = object()
 
@@ -60,10 +65,10 @@ def fetch_block(store: BlockStore, i: int):
 
 
 def block_nbytes(blk) -> int:
-    """Host->device bytes of one produced block (wire bytes for EncodedBlock)."""
-    if isinstance(blk, EncodedBlock):
-        return blk.payload.nbytes + blk.scale.nbytes
-    return getattr(blk, "nbytes", 0)
+    """Host->device bytes of one produced block: the sum over its pytree
+    leaves (an array, the wire form of an EncodedBlock, the three arrays of
+    a CSRBlock)."""
+    return sum(getattr(leaf, "nbytes", 0) for leaf in jax.tree.leaves(blk))
 
 # Labeled engine-pass telemetry lives in the obs metrics registry under
 # "engine.passes.<label>"; reset_pass_counts() scopes a measurement.

@@ -126,3 +126,25 @@ def test_kernel_compiles_for_v5e(case, one_chip, no_persistent_cache):
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.memory_analysis() is not None
+
+
+# RCV1 row of Table 1: one 4,096-row CSR block of ~307k nonzeros, padded to
+# the store's capacity, embedded against l=300 landmarks of d=47,236.
+RCV1_D, RCV1_SLOTS = 47236, 313216
+
+
+def test_sparse_embed_compiles_for_v5e_at_rcv1_widths(one_chip, no_persistent_cache):
+    """The sparse embed is XLA (a gather and sorted segment sums): it must
+    compile without a (slots, l) temporary of gathered L^T rows, which would
+    be 376 MB a block."""
+    def spec(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = ops._sparse_embed_block.lower(
+        spec((RCV1_SLOTS,)), spec((RCV1_SLOTS,), jnp.int32), spec((N + 1,), jnp.int32),
+        spec((RCV1_D, L)), spec((1, L)), spec((1, M, L)),
+        kernel=Kernel("rbf", gamma=0.5),
+    ).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 4 * RCV1_SLOTS * L // 4
+    assert mem.output_size_in_bytes == 4 * N * M
